@@ -20,6 +20,7 @@ import argparse
 import jax
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.api import BatchingPolicy, deploy_lm
 from repro.serving.generation import GenerationSpec, token_service_ms
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--straggle-ms", type=float, default=120.0)
     ap.add_argument("--sim-tokens", type=int, default=8000)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # threads engine: real model, one deliberately slow member ------------
     cfg = get_config("qwen2-0.5b", reduced=True)

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core.parity import train_parity_models
 from repro.data.pipeline import batched, cluster_images
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.cnn import build
 from repro.serving.api import BatchingPolicy, DeploymentSpec, Trace, deploy
 from repro.training.loss import softmax_xent
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--batch-size", type=int, default=1,
                     help="adaptive-batching max batch size (main pool)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # train deployed + parity models ---------------------------------------
     x, y, tmpl = cluster_images(3000, noise=2.0, seed=0, image_shape=IMG)

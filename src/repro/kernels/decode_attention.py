@@ -38,7 +38,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     s = s.reshape(H, block_k)
 
     kpos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-    valid = kpos <= pos_ref[0]
+    valid = kpos <= pos_ref[pl.program_id(0)]
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -60,11 +60,16 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def decode_attention(q, k_cache, v_cache, pos, *, block_k=512,
+def decode_attention(q, k_cache, v_cache, pos, *, block_k=128,
                      interpret=False):
     """q [B,H,hd]; caches [B,S,KV,hd]; pos scalar int32 or [B] per-row
     positions (slot-batched decode: each batch row is an independent stream
-    at its own position). Returns [B,H,hd]."""
+    at its own position). Returns [B,H,hd].
+
+    ``block_k`` bounds the VMEM working set: the fp32 copies of one K and V
+    block and their head-major relayouts cost ~40 bytes per cached element,
+    so at KV*hd = 2048 (OLMo-1B) a 256-row block already overflows Mosaic's
+    16 MiB scoped-VMEM default on a v5e."""
     B, H, hd = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
     rep = H // KV
@@ -75,15 +80,16 @@ def decode_attention(q, k_cache, v_cache, pos, *, block_k=512,
         _decode_kernel, scale=hd ** -0.5, block_k=block_k, n_kv_blocks=nk,
         kv_heads=KV, rep=rep)
 
-    # A scalar pos broadcasts to [B]; each grid row b then streams its own
-    # pos_ref[0], so per-row positions reuse the same kernel body.
+    # A scalar pos broadcasts to [B]; the whole [B] vector sits in SMEM
+    # (a (1,)-block of it is not a legal Mosaic tile) and grid row b reads
+    # its own pos_ref[b], so per-row positions reuse the same kernel body.
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
 
     return pl.pallas_call(
         kernel,
         grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,)),                 # pos
+            pl.BlockSpec(memory_space=pltpu.SMEM),                 # pos [B]
             pl.BlockSpec((1, H, hd), lambda b, j: (b, 0, 0)),      # q
             pl.BlockSpec((1, block_k, KV, hd), lambda b, j: (b, j, 0, 0)),
             pl.BlockSpec((1, block_k, KV, hd), lambda b, j: (b, j, 0, 0)),

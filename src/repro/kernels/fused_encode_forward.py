@@ -29,17 +29,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _fused_kernel(c_ref, q_ref, w_ref, o_ref, acc_ref, *, k, nf, f_total,
                   block_f):
-    # c_ref [1, k]; q_ref [k, bb, bf]; w_ref [1, bf, bv]; o_ref [1, bb, bv];
-    # acc_ref [bb, bv] fp32 scratch, live across the F grid axis
+    # c_ref [r, k] whole in SMEM; q_ref [k, bb, bf]; w_ref [1, bf, bv];
+    # o_ref [1, bb, bv]; acc_ref [bb, bv] fp32 scratch, live across the F
+    # grid axis
+    j = pl.program_id(0)
     f = pl.program_id(3)
 
     @pl.when(f == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    enc = q_ref[0].astype(jnp.float32) * c_ref[0, 0]
+    enc = q_ref[0].astype(jnp.float32) * c_ref[j, 0]
     for i in range(1, k):
-        enc += q_ref[i].astype(jnp.float32) * c_ref[0, i]
+        enc += q_ref[i].astype(jnp.float32) * c_ref[j, i]
     w = w_ref[0].astype(jnp.float32)
     if f_total % block_f:
         # a trailing partial F block is padded with UNDEFINED values — zero
@@ -73,7 +75,9 @@ def fused_encode_forward(queries, coeffs, weights, *, block_b=8, block_f=512,
                           block_f=block_f),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, k), lambda j, b, v, f: (j, 0)),    # coeffs row j
+            # coeffs [r, k] whole in SMEM: a (1, k) row block is not a
+            # legal (8, 128)-aligned VMEM tile once r > 1
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((k, block_b, block_f),
                          lambda j, b, v, f: (0, b, f)),
             pl.BlockSpec((1, block_f, block_v),

@@ -17,13 +17,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _project_kernel(w_ref, h_ref, o_ref, *, hidden):
-    # w_ref block: [H, 1] (column j); h_ref: [H, bb, bf]; o_ref: [1, bb, bf]
-    acc = h_ref[0].astype(jnp.float32) * w_ref[0, 0]
+    # w_ref: [H, r] whole in SMEM; h_ref: [H, bb, bf]; o_ref: [1, bb, bf]
+    j = pl.program_id(0)
+    acc = h_ref[0].astype(jnp.float32) * w_ref[0, j]
     for i in range(1, hidden):
-        acc += h_ref[i].astype(jnp.float32) * w_ref[i, 0]
+        acc += h_ref[i].astype(jnp.float32) * w_ref[i, j]
     o_ref[0] = acc.astype(o_ref.dtype)
 
 
@@ -40,7 +42,9 @@ def learned_project(h, w, *, block_b=8, block_f=512, interpret=False):
         functools.partial(_project_kernel, hidden=H),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((H, 1), lambda j, i, b: (0, j)),     # W column j
+            # the tiny [H, r] weight lives whole in SMEM: an (H, 1) column
+            # block is not a legal (8, 128)-aligned VMEM tile once r > 1
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((H, block_b, block_f), lambda j, i, b: (0, i, b)),
         ],
         out_specs=pl.BlockSpec((1, block_b, block_f),

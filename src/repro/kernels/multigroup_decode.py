@@ -26,15 +26,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _mg_decode_kernel(c_ref, p_ref, outs_ref, o_ref, *, k):
-    # c_ref [1, k+1] (avail coeffs + inv_c); p_ref [1, bb, bv];
-    # outs_ref [1, k, bb, bv]; o_ref [1, bb, bv]
+    # c_ref [G, k+1] whole in SMEM (avail coeffs + inv_c per group);
+    # p_ref [1, bb, bv]; outs_ref [1, k, bb, bv]; o_ref [1, bb, bv]
+    g = pl.program_id(0)
     acc = p_ref[0].astype(jnp.float32)
     for i in range(k):
-        acc -= outs_ref[0, i].astype(jnp.float32) * c_ref[0, i]
-    o_ref[0] = (acc * c_ref[0, k]).astype(o_ref.dtype)
+        acc -= outs_ref[0, i].astype(jnp.float32) * c_ref[g, i]
+    o_ref[0] = (acc * c_ref[g, k]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "block_v",
@@ -52,7 +54,9 @@ def multigroup_decode(parity_outs, outputs, cmat, *, block_b=8, block_v=512,
         functools.partial(_mg_decode_kernel, k=k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, k + 1), lambda g, b, v: (g, 0)),
+            # the tiny [G, k+1] table lives whole in SMEM: a (1, k+1) row
+            # block is not a legal (8, 128)-aligned VMEM tile
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_b, block_v), lambda g, b, v: (g, b, v)),
             pl.BlockSpec((1, k, block_b, block_v),
                          lambda g, b, v: (g, 0, b, v)),

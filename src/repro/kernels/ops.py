@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On a real TPU these call the Mosaic-compiled kernels; on this CPU container
-they run in ``interpret=True`` mode (Python-evaluated, numerically identical)
-— selected automatically from the backend so the same call sites work in
-tests, benches and the serving runtime.
+On a TPU these call the Mosaic-compiled kernels; on the CPU backend they
+run in ``interpret=True`` mode (the kernel body evaluated as plain JAX ops),
+so the same call sites work in tests, benches and the serving runtime.  Any
+other backend raises: a kernel never silently falls back to the
+interpreter where the device was meant to run it.
 """
 from __future__ import annotations
 
@@ -22,7 +23,12 @@ from repro.kernels.decode_attention import decode_attention as _decode_attn
 
 
 def _interpret():
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+            f"the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def parity_encode_op(queries, coeffs, **kw):

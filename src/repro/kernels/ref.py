@@ -33,6 +33,19 @@ def fused_encode_forward_ref(queries, coeffs, weights):
     return out.astype(queries.dtype)
 
 
+def learned_project_ref(h, w):
+    """h [H, B, F]; w [H, r] -> [r, B, F]: out[j] = sum_h W[h, j] * H[h]
+    (fp32 accumulate)."""
+    out = jnp.einsum("hr,hbf->rbf", w.astype(jnp.float32),
+                     h.astype(jnp.float32))
+    return out.astype(h.dtype)
+
+
+def berrut_encode_ref(q, c):
+    """q [k, B, F]; c [r, k] -> [r, B, F]: out[j] = sum_i C[j, i] * Q[i]."""
+    return learned_project_ref(q, c.T)
+
+
 def multigroup_decode_ref(parity_outs, outputs, cmat):
     """parity_outs [G, B, V]; outputs [G, k, B, V]; cmat [G, k+1] (per-group
     availability-masked coeffs, 0 at the missing index, with 1/c_missing

@@ -8,8 +8,11 @@ Usage:
 """
 # The dry-run (and ONLY the dry-run) needs 512 placeholder devices so
 # jax.make_mesh can build the production mesh. This MUST precede any other
-# import — jax locks the device count on first init.
+# import — jax locks the device count on first init.  The placeholders are
+# host devices: the dry-run is a CPU-only tool and never takes an attached
+# accelerator, which another process may be using.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))

@@ -14,18 +14,12 @@ import; regular tests and benches see the 1 real CPU device.
 from __future__ import annotations
 
 import jax
-
-try:                                   # jax >= 0.5 has explicit axis types
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: meshes are Auto already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes, devices):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -43,7 +37,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU integration tests (requires forced host devices)."""
+    """Small mesh over the first devices: CPU integration tests (forced host
+    devices) and the four chips of one TPU host (``chip_smoke.py``)."""
     n = 1
     for s in shape:
         n *= s

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs.base import ARCH_IDS, get_config
 from repro.data.pipeline import lm_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.api import BatchingPolicy, DeploymentSpec, deploy
 from repro.serving.strategy import available_strategies
@@ -50,6 +51,7 @@ def main():
     ap.add_argument("--parity-steps", type=int, default=40)
     ap.add_argument("--straggle-ms", type=float, default=120.0)
     args = ap.parse_args()
+    enable_compile_cache()
     if get_config(args.arch).enc_dec or get_config(args.arch).family == "vlm":
         print("note: modality archs serve text-side queries here; frame/"
               "patch embeddings would ride along in production")

@@ -191,10 +191,13 @@ class PredictionFuture:
         return self._query.event.is_set()
 
     def result(self, timeout: Optional[float] = None):
-        """Block until the prediction is available (or raise TimeoutError)."""
+        """Block until the prediction is available (or raise TimeoutError);
+        re-raises the exception the serving instance raised, if any."""
         if not self._query.event.wait(timeout):
             raise TimeoutError(
                 f"query {self._query.qid} unanswered after {timeout}s")
+        if self._query.error is not None:
+            raise self._query.error
         return self._query.result
 
     @property
@@ -226,6 +229,18 @@ class PredictionFuture:
         return f"PredictionFuture(qid={self.qid}, {state})"
 
 
+def exit_session(session, exc_type):
+    """``__exit__`` body of every serving session: shut down, and let a
+    serving error that ``shutdown`` re-raises propagate unless the ``with``
+    body is already propagating one of its own."""
+    try:
+        session.shutdown()
+    except Exception:
+        if exc_type is None:
+            raise
+    return False
+
+
 class Session:
     """Base of both engines: context-managed shutdown + report access."""
 
@@ -246,9 +261,8 @@ class Session:
     def __enter__(self):
         return self
 
-    def __exit__(self, *exc):
-        self.shutdown()
-        return False
+    def __exit__(self, exc_type, *exc):
+        return exit_session(self, exc_type)
 
 
 class ThreadsSession(Session):

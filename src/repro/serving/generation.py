@@ -46,6 +46,7 @@ Engines:
 """
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -57,7 +58,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.scheme import get_scheme
-from repro.serving.api import BatchingPolicy, DeploymentSpec, Trace, deploy
+from repro.serving.api import (BatchingPolicy, DeploymentSpec, Trace, deploy,
+                               exit_session)
 from repro.serving.report import ServingReport
 from repro.serving.scenarios import get_scenario, instance_id
 
@@ -144,7 +146,8 @@ class GenerationSpec:
 class GenerationFuture:
     """Async handle for one generation request: the emitted token ids, how
     many steps were served from a parity reconstruction, and the per-token
-    emission timestamps."""
+    emission timestamps.  ``result()`` re-raises the exception that stopped
+    the session, if one did."""
 
     def __init__(self, rid):
         self.rid = rid
@@ -153,7 +156,8 @@ class GenerationFuture:
         self._tokens: List[int] = []
         self._recon_steps = 0
         self._times: List[float] = []
-        self.completed_by = None         # "model" | "flushed"
+        self._error: Optional[BaseException] = None
+        self.completed_by = None         # "model" | "error"
 
     def done(self) -> bool:
         return self._event.is_set()
@@ -162,6 +166,8 @@ class GenerationFuture:
         if not self._event.wait(timeout):
             raise TimeoutError(
                 f"request {self.rid} unfinished after {timeout}s")
+        if self._error is not None:
+            raise self._error
         return list(self._tokens)
 
     @property
@@ -189,6 +195,11 @@ class GenerationFuture:
     def _finish(self, how="model"):
         self.completed_by = how
         self._event.set()
+
+    def _fail(self, error):
+        if not self._event.is_set():
+            self._error = error
+            self._finish("error")
 
     def __repr__(self):
         state = (self.completed_by or "done") if self.done() else "pending"
@@ -240,7 +251,7 @@ class _Executor(threading.Thread):
             fn, evt, out = job
             try:
                 out["result"] = fn()
-            except Exception as e:        # surfaced at collection time
+            except Exception as e:        # re-raised by the scheduler
                 out["error"] = e
             evt.set()
 
@@ -252,19 +263,45 @@ class _Executor(threading.Thread):
 # Default substrate: repro.models.transformer
 # --------------------------------------------------------------------------
 def _transformer_fns(spec):
+    return _transformer_substrate(spec.cfg, spec.mesh)
+
+
+@functools.lru_cache(maxsize=16)
+def _transformer_substrate(cfg, mesh):
+    """The jitted substrate of one (config, mesh): shared by every session
+    that serves it, so each shape compiles once per process."""
     from repro.models import transformer as T
-    cfg = spec.cfg
+
+    # jitted: an eager prefill re-traces its layer scan, and so recompiles
+    # the whole stack, on every call
+    prefill_tok = jax.jit(
+        lambda params, tokens, cache_len: T.prefill(
+            cfg, params, tokens=tokens, cache_len=cache_len),
+        static_argnums=2)
+    prefill_emb = jax.jit(
+        lambda params, embeds, cache_len: T.prefill(
+            cfg, params, embeds=embeds, cache_len=cache_len),
+        static_argnums=2)
 
     def prefill_fn(params, tokens=None, embeds=None, cache_len=0):
-        return T.prefill(cfg, params, tokens=tokens, embeds=embeds,
-                         cache_len=cache_len)
+        if embeds is not None:
+            return prefill_emb(params, embeds, cache_len)
+        return prefill_tok(params, tokens, cache_len)
+
+    def step(params, cache, pos, **inp):
+        logits, new = T.decode_step(cfg, params, cache, pos, **inp)
+        if mesh is not None:
+            # the new cache keeps the pool layout the step was compiled for
+            new = jax.lax.with_sharding_constraint(
+                new, cache_shardings(mesh, new))
+        return logits, new
 
     decode_jit = jax.jit(
-        lambda params, cache, pos, token: T.decode_step(
-            cfg, params, cache, pos, token=token))
+        lambda params, cache, pos, token: step(params, cache, pos,
+                                               token=token))
     decode_emb_jit = jax.jit(
-        lambda params, cache, pos, embed: T.decode_step(
-            cfg, params, cache, pos, embed=embed))
+        lambda params, cache, pos, embed: step(params, cache, pos,
+                                               embed=embed))
 
     def decode_fn(params, cache, pos, token=None, embed=None):
         if embed is not None:
@@ -305,6 +342,13 @@ def place_inference_params(params, mesh):
     return jax.tree.map(jax.device_put, params, shardings)
 
 
+def cache_shardings(mesh, cache):
+    """The inference layout of a cache pool on ``mesh``
+    (``ShardingRules.cache_specs``); ``cache`` may hold arrays or tracers."""
+    from repro.distributed.sharding import ShardingRules
+    return ShardingRules(mesh, fsdp_params=False).cache_specs(cache)
+
+
 # --------------------------------------------------------------------------
 # Threads engine
 # --------------------------------------------------------------------------
@@ -338,10 +382,11 @@ class GenerationSession:
         self.params, self.parity_params = params, pparams
 
         # one fixed-shape cache pool per instance; slots never reshape
-        self._caches = [self._init_cache(params, self.n_slots, self.max_seq)
+        self._caches = [self._lay_out(self._init_cache(params, self.n_slots,
+                                                       self.max_seq))
                         for _ in range(self.k)]
-        self._pcaches = [self._init_cache(pparams, self.n_slots,
-                                          self.max_seq)
+        self._pcaches = [self._lay_out(self._init_cache(pparams, self.n_slots,
+                                                        self.max_seq))
                          for _ in range(self.r)]
         self._ppos = np.zeros((self.r, self.n_slots), np.int64)
 
@@ -367,25 +412,33 @@ class GenerationSession:
         self._parity_iids = [instance_id(f"parity{j}", 0)
                              for j in range(self.r)]
 
-        self._members = [_Executor(f"lm-member-{i}") for i in range(self.k)]
-        self._parities = [_Executor(f"lm-parity-{j}") for j in range(self.r)]
-        for ex in self._members + self._parities:
-            ex.start()
-
         # warm the decode paths (jit compile) before any deadline is armed —
         # a first-step compile would otherwise read as a multi-second
         # straggle on every instance at once, which no code survives
         tok0 = jnp.zeros((self.n_slots, 1), jnp.int32)
         pos0 = jnp.zeros((self.n_slots,), jnp.int32)
-        self._decode(self.params, self._caches[0], pos0, token=tok0)
+        logits0, _ = self._decode(self.params, self._caches[0], pos0,
+                                  token=tok0)
         self._decode(self.parity_params, self._pcaches[0], pos0,
                      embed=self._embed(self.params, tok0))
+        # ... and the reconstruction decode (its shapes never change), whose
+        # first call would otherwise stall a step past its deadline
+        zeros = jnp.zeros(np.shape(logits0), jnp.float32)
+        self.scheme.decode(jnp.stack([zeros] * self.r),
+                           jnp.stack([zeros] * self.k),
+                           jnp.arange(self.k) == 0, jnp.ones((self.r,), bool))
+
+        self._members = [_Executor(f"lm-member-{i}") for i in range(self.k)]
+        self._parities = [_Executor(f"lm-parity-{j}") for j in range(self.r)]
+        for ex in self._members + self._parities:
+            ex.start()
 
         self._waiting: "queue.Queue" = queue.Queue()
         self._lock = threading.Lock()
         self._stopping = False
         self._idle = threading.Event()   # set while nothing queued/active
         self._idle.set()
+        self._error: Optional[BaseException] = None
         self._gaps_ms: List[float] = []
         self._completed_by: Dict[str, int] = {}
         self._recon_steps = 0
@@ -400,19 +453,28 @@ class GenerationSession:
     def submit(self, prompt, max_new_tokens=None) -> GenerationFuture:
         """Queue one generation request (prompt: sequence of token ids)."""
         with self._lock:
+            if self._error is not None:
+                raise RuntimeError("session failed") from self._error
             if self._stopping:
                 raise RuntimeError("session is shut down")
             rid = self._next_rid
             self._next_rid += 1
-        fut = GenerationFuture(rid)
-        self._idle.clear()
-        self._waiting.put((rid, [int(t) for t in prompt],
-                           max_new_tokens or self.spec.max_new_tokens, fut))
+            # queued under the lock: an abort either sees this request in
+            # its drain or this submit already raised
+            fut = GenerationFuture(rid)
+            self._idle.clear()
+            self._waiting.put((rid, [int(t) for t in prompt],
+                               max_new_tokens or self.spec.max_new_tokens,
+                               fut))
         return fut
 
     def wait_all(self, timeout: float = 120.0) -> bool:
-        """Block until every submitted request has finished."""
-        return self._idle.wait(timeout)
+        """Block until every submitted request has finished; re-raises the
+        exception that stopped the scheduler, as soon as it stops."""
+        done = self._idle.wait(timeout)
+        if self._error is not None:
+            raise self._error
+        return done
 
     def stats(self) -> ServingReport:
         with self._lock:
@@ -437,22 +499,32 @@ class GenerationSession:
                 reconstructed_steps=self._recon_steps)
 
     def shutdown(self):
+        """Stop the scheduler and the executors; re-raises the exception
+        that stopped the scheduler, if one did."""
         with self._lock:
-            if self._stopping:
-                return
-            self._stopping = True
-        self._scheduler.join(timeout=60.0)
-        for ex in self._members + self._parities:
-            ex.stop()
-        for ex in self._members + self._parities:
-            ex.join(timeout=10.0)
+            stopped, self._stopping = self._stopping, True
+        if not stopped:
+            self._scheduler.join(timeout=60.0)
+            for ex in self._members + self._parities:
+                ex.stop()
+            for ex in self._members + self._parities:
+                ex.join(timeout=10.0)
+        if self._error is not None:
+            raise self._error
 
     def __enter__(self):
         return self
 
-    def __exit__(self, *exc):
-        self.shutdown()
-        return False
+    def __exit__(self, exc_type, *exc):
+        return exit_session(self, exc_type)
+
+    def _lay_out(self, cache):
+        """Put a cache pool on the mesh's inference layout (a no-op without
+        a mesh): the pools start there and every slot write lands back
+        there, so the decode step never sees a new input layout."""
+        if self.spec.mesh is None:
+            return cache
+        return jax.device_put(cache, cache_shardings(self.spec.mesh, cache))
 
     # -- scheduler ---------------------------------------------------------
     def _active(self):
@@ -460,6 +532,29 @@ class GenerationSession:
                 if self._slots[i][s] is not None]
 
     def _loop(self):
+        try:
+            self._serve()
+        except Exception as e:
+            self._abort(e)
+
+    def _abort(self, error):
+        """The scheduler hit an exception (its own, or an executor's it
+        re-raised): fail every admitted and queued request with it and wake
+        ``wait_all`` — nothing is left to wait out a timeout."""
+        with self._lock:
+            self._error = error
+        for row in self._slots:
+            for st in row:
+                if st is not None:
+                    st.future._fail(error)
+        while True:
+            try:
+                self._waiting.get_nowait()[3]._fail(error)
+            except queue.Empty:
+                break
+        self._idle.set()
+
+    def _serve(self):
         while True:
             self._admit()
             active = self._active()
@@ -515,9 +610,9 @@ class GenerationSession:
                     time.sleep(d)
                 logits, one = self._prefill(self.params, tokens=toks,
                                             cache_len=self.max_seq)
-                self._caches[i] = jax.tree.map(
+                self._caches[i] = self._lay_out(jax.tree.map(
                     lambda pool, new: pool.at[:, s:s + 1].set(new),
-                    self._caches[i], one)
+                    self._caches[i], one))
                 return np.asarray(logits[0, -1])
 
             evt, out = ex.submit(job)
@@ -580,9 +675,9 @@ class GenerationSession:
             def job(enc=enc, j=j, s=s):
                 _, one = self._prefill(self.parity_params, embeds=enc,
                                        cache_len=self.max_seq)
-                self._pcaches[j] = jax.tree.map(
+                self._pcaches[j] = self._lay_out(jax.tree.map(
                     lambda pool, new: pool.at[:, s:s + 1].set(new),
-                    self._pcaches[j], one)
+                    self._pcaches[j], one))
                 return None
 
             evt, out = self._parities[j].submit(job)

@@ -103,6 +103,7 @@ class Query:
     result: Optional[np.ndarray] = None
     completed_by: str = ""
     finish: float = 0.0
+    error: Optional[BaseException] = None
 
     def fulfill(self, result, how, now=None):
         if not self.event.is_set():
@@ -110,6 +111,13 @@ class Query:
             self.completed_by = how
             self.finish = now or time.perf_counter()
             self.event.set()
+
+    def fail(self, error):
+        """Finish with the exception an instance raised serving this query;
+        ``PredictionFuture.result`` re-raises it."""
+        if not self.event.is_set():
+            self.error = error
+            self.fulfill(None, "error")
 
     @property
     def latency_ms(self):
@@ -129,7 +137,10 @@ class ModelInstance(threading.Thread):
     record every batch-mate before any decode decision runs (delivering them
     one at a time would let a parity decode "reconstruct" a member whose
     exact output sits later in the same batch).  ``on_batch(n)`` —
-    bookkeeping callback, once per inference call.
+    bookkeeping callback, once per inference call.  ``on_error(items,
+    exc)`` — an exception raised while serving a dequeued batch is handed
+    over with the batch's items and the worker keeps serving; without the
+    hook it would kill the thread and leave those futures to hang.
     """
 
     def __init__(self, iid, pool_q, fwd, params, on_done,
@@ -138,7 +149,8 @@ class ModelInstance(threading.Thread):
                  batching: Optional[BatchingPolicy] = None,
                  on_batch: Optional[Callable[[int], None]] = None,
                  on_done_batch: Optional[Callable] = None,
-                 corrupt_fn: Optional[Callable[[int], bool]] = None):
+                 corrupt_fn: Optional[Callable[[int], bool]] = None,
+                 on_error: Optional[Callable] = None):
         super().__init__(daemon=True)
         self.iid = iid
         self.pool_q = pool_q
@@ -151,6 +163,7 @@ class ModelInstance(threading.Thread):
         self.on_batch = on_batch
         self.on_done_batch = on_done_batch
         self.corrupt_fn = corrupt_fn
+        self.on_error = on_error
         self.stop = False
 
     def _maybe_corrupt(self, out):
@@ -200,46 +213,54 @@ class ModelInstance(threading.Thread):
                 items = self._collect(item)
             else:
                 items = [item]
-            if self.delay_fn:
-                d = self.delay_fn(self.iid)
-                if d > 0:
-                    time.sleep(d)
-            if len(items) == 1:
-                tag, payload, x = items[0]
-                out = self._maybe_corrupt(np.asarray(self.fwd(self.params,
-                                                              x)))
+            try:
+                self._serve(items)
+            except Exception as e:
+                if self.on_error is None:
+                    raise
+                self.on_error(items, e)
+
+    def _serve(self, items):
+        """Serve one dequeued batch: delay, inference, completion."""
+        if self.delay_fn:
+            d = self.delay_fn(self.iid)
+            if d > 0:
+                time.sleep(d)
+        if len(items) == 1:
+            tag, payload, x = items[0]
+            out = self._maybe_corrupt(np.asarray(self.fwd(self.params, x)))
+            if self.on_batch is not None:
+                self.on_batch(1)
+            self.on_done(tag, payload, out)
+        else:
+            # one inference call per trailing-shape group: same-shape
+            # queries stack along the leading batch dim and the output
+            # splits back per item.  Mixed shapes are NOT padded — for a
+            # general fwd, padding would change the outputs — they just
+            # cost one extra call, instead of a ValueError that would
+            # kill the worker and hang every dequeued future
+            groups = {}
+            for i, it in enumerate(items):
+                groups.setdefault(np.shape(it[2])[1:], []).append(i)
+            outs = [None] * len(items)
+            for idxs in groups.values():
+                stacked = np.concatenate([items[i][2] for i in idxs],
+                                         axis=0)
+                out = self._maybe_corrupt(
+                    np.asarray(self.fwd(self.params, stacked)))
                 if self.on_batch is not None:
-                    self.on_batch(1)
-                self.on_done(tag, payload, out)
+                    self.on_batch(len(idxs))
+                ofs = 0
+                for i in idxs:
+                    sz = items[i][2].shape[0]
+                    outs[i] = out[ofs:ofs + sz]
+                    ofs += sz
+            if self.on_done_batch is not None:
+                self.on_done_batch(
+                    [(it[1], o) for it, o in zip(items, outs)])
             else:
-                # one inference call per trailing-shape group: same-shape
-                # queries stack along the leading batch dim and the output
-                # splits back per item.  Mixed shapes are NOT padded — for a
-                # general fwd, padding would change the outputs — they just
-                # cost one extra call, instead of a ValueError that would
-                # kill the worker and hang every dequeued future
-                groups = {}
-                for i, it in enumerate(items):
-                    groups.setdefault(np.shape(it[2])[1:], []).append(i)
-                outs = [None] * len(items)
-                for idxs in groups.values():
-                    stacked = np.concatenate([items[i][2] for i in idxs],
-                                             axis=0)
-                    out = self._maybe_corrupt(
-                        np.asarray(self.fwd(self.params, stacked)))
-                    if self.on_batch is not None:
-                        self.on_batch(len(idxs))
-                    ofs = 0
-                    for i in idxs:
-                        sz = items[i][2].shape[0]
-                        outs[i] = out[ofs:ofs + sz]
-                        ofs += sz
-                if self.on_done_batch is not None:
-                    self.on_done_batch(
-                        [(it[1], o) for it, o in zip(items, outs)])
-                else:
-                    for (tag, payload, _), o in zip(items, outs):
-                        self.on_done(tag, payload, o)
+                for (tag, payload, _), o in zip(items, outs):
+                    self.on_done(tag, payload, o)
 
 
 class ParMFrontend:
@@ -409,6 +430,7 @@ class ParMFrontend:
         self._timers = set()    # armed default_slo timers; cancelled at
                                 # shutdown so none fires into a dead frontend
         self._shutdown = False
+        self._error = None      # first exception an instance raised
         self.cancelled_queries = 0    # tombstoned originals skipped at dequeue
         self.cancelled_parities = 0   # undispatched parities dropped
         self._n_batches = 0           # main-pool inference calls
@@ -479,7 +501,8 @@ class ParMFrontend:
                               batching=main_batching,
                               on_batch=self._note_batch,
                               on_done_batch=self._on_model_batch_done,
-                              corrupt_fn=corrupt_fn)
+                              corrupt_fn=corrupt_fn,
+                              on_error=self._on_worker_error)
             w.start()
             self.workers.append(w)
             self._main_workers.append(w)
@@ -512,7 +535,8 @@ class ParMFrontend:
                                       parity_params[j],
                                       self._on_parity_done, delay_fn,
                                       skip_fn=self._should_skip,
-                                      corrupt_fn=corrupt_fn)
+                                      corrupt_fn=corrupt_fn,
+                                      on_error=self._on_worker_error)
                     w.start()
                     self.workers.append(w)
             self.parity_q = self.parity_qs[0]      # back-compat alias
@@ -566,7 +590,7 @@ class ParMFrontend:
             recs = []
             for qid, q in self.queries.items():
                 if qid in self._window_counted or not q.event.is_set() \
-                        or q.completed_by == "flushed":
+                        or q.completed_by in ("flushed", "error"):
                     continue
                 fin_ms = (q.finish - self._origin) * 1e3 / ts
                 if fin_ms < t1:
@@ -819,6 +843,19 @@ class ParMFrontend:
                 self.queries[qid].fulfill(out, "model")
             self._decode_touched(touched)
 
+    def _on_worker_error(self, items, error):
+        """An instance raised while serving ``items``: fail their queries
+        with the exception so ``result()`` re-raises it, and keep the first
+        one for ``wait_all`` / ``shutdown``.  A failed parity item fails no
+        member — its group-mates still complete on the model path."""
+        with self.lock:
+            if self._error is None:
+                self._error = error
+            for tag, payload, _ in items:
+                q = self.queries.get(payload) if tag == "query" else None
+                if q is not None:
+                    q.fail(error)
+
     def _on_parity_done(self, tag, key, out):
         gid, j = key
         with self.lock:
@@ -1044,15 +1081,20 @@ class ParMFrontend:
 
     # ------------------------------------------------------------------
     def wait_all(self, timeout=60.0):
+        """Block until every submitted query is answered; re-raises the
+        first exception an instance raised as soon as it is seen."""
         deadline = time.time() + timeout
-        for q in self.queries.values():
+        for q in list(self.queries.values()):
             q.event.wait(max(0.0, deadline - time.time()))
+            if self._error is not None:
+                raise self._error
         return all(q.event.is_set() for q in self.queries.values())
 
     def shutdown(self):
         """Idempotent teardown: cancel armed SLO timers, wake every worker
         with a shutdown sentinel (blocking ``get`` — no poll loop to time
-        out), flush the partial trailing coding group."""
+        out), flush the partial trailing coding group.  Re-raises the first
+        exception an instance raised, once teardown is complete."""
         with self.lock:
             already = self._shutdown
             self._shutdown = True
@@ -1100,12 +1142,15 @@ class ParMFrontend:
             # end of arrivals), so the decision sequences stay comparable
             while self._close_window():
                 pass
+        if self._error is not None:
+            raise self._error
 
     def stats(self) -> ServingReport:
         """Typed ``ServingReport`` (dict-compatible) with the same fields the
         DES (``repro.serving.simulator.simulate``) reports. Queries flushed
-        at shutdown appear in ``completed_by`` but are excluded from the
-        latency numbers — their finish time is a shutdown artifact."""
+        at shutdown or failed by an instance error appear in
+        ``completed_by`` but are excluded from the latency numbers — their
+        finish time is not a serving latency."""
         with self.lock:
             queries = list(self.queries.values())
             cq, cp = self.cancelled_queries, self.cancelled_parities
@@ -1114,7 +1159,8 @@ class ParMFrontend:
             adjustments = tuple(self._adjust_log)
             windows, ps = self._window_idx, self.parity_served
         lats = np.array([q.latency_ms for q in queries
-                         if q.event.is_set() and q.completed_by != "flushed"])
+                         if q.event.is_set()
+                         and q.completed_by not in ("flushed", "error")])
         by = {}
         for q in queries:
             if q.completed_by:

@@ -391,3 +391,24 @@ def test_flushed_future_never_reports_deadline_exceeded():
     sess.shutdown()
     assert fut.completed_by == "flushed"
     assert fut.deadline_exceeded is False
+
+
+def test_fwd_error_surfaces_from_future_wait_all_and_shutdown():
+    """An instance whose fwd raises fails its queries with the original
+    exception — the worker survives and nothing waits out a timeout."""
+    def broken_fwd(p, x):
+        raise ValueError("fwd exploded")
+
+    sess = deploy(_spec(fwd=broken_fwd))
+    x = np.ones((1, 8), np.float32)
+    futs = [sess.submit(x) for _ in range(2)]
+    t0 = time.perf_counter()
+    with pytest.raises(ValueError, match="fwd exploded"):
+        futs[0].result(timeout=10.0)
+    with pytest.raises(ValueError, match="fwd exploded"):
+        sess.wait_all(timeout=10.0)
+    assert time.perf_counter() - t0 < 2.0
+    assert all(f.completed_by == "error" for f in futs)
+    assert sess.stats().n == 0            # failures carry no latency
+    with pytest.raises(ValueError, match="fwd exploded"):
+        sess.shutdown()

@@ -274,3 +274,30 @@ def test_deploy_lm_rejects_bad_engine_and_spec():
         GenerationSpec(params=params, k=0, **fns)
     with pytest.raises(RuntimeError):
         LMSimSession(spec).stats()
+
+
+def test_decode_error_reaches_wait_all_promptly():
+    """A decode_fn that raises stops the scheduler; wait_all, result() and
+    shutdown() re-raise the original exception instead of hanging."""
+    params, fns = _linear_substrate()
+    armed = []
+
+    def decode_fn(p, cache, pos, token=None, embed=None):
+        if armed:                       # the construction warm-up passes
+            raise RuntimeError("decode exploded")
+        return fns["decode_fn"](p, cache, pos, token=token, embed=embed)
+
+    sess = deploy_lm(_spec(params, {**fns, "decode_fn": decode_fn}))
+    armed.append(True)
+    futs = [sess.submit(p) for p in _prompts(3)]
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError, match="decode exploded"):
+        sess.wait_all(60.0)
+    assert time.monotonic() - t0 < 2.0
+    for f in futs:
+        with pytest.raises(RuntimeError, match="decode exploded"):
+            f.result(1.0)
+    with pytest.raises(RuntimeError, match="session failed"):
+        sess.submit([1, 2])
+    with pytest.raises(RuntimeError, match="decode exploded"):
+        sess.shutdown()
