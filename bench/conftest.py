@@ -1,0 +1,6 @@
+import os
+import sys
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
