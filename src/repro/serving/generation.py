@@ -51,7 +51,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field, replace
-from typing import Any, Callable, Dict, List, Optional, Union
+from typing import Any, Callable, List, Optional, Union
 
 import numpy as np
 import jax
@@ -62,8 +62,10 @@ from repro.serving.api import (BatchingPolicy, DeploymentSpec, Trace, deploy,
                                exit_session)
 from repro.serving.report import ServingReport
 from repro.serving.scenarios import get_scenario, instance_id
+from repro.serving.tracing import RECORDER, AdmitRecord, StepRecord
 
 _SHUTDOWN = object()
+_span = RECORDER.span
 
 
 # --------------------------------------------------------------------------
@@ -147,15 +149,22 @@ class GenerationFuture:
     """Async handle for one generation request: the emitted token ids, how
     many steps were served from a parity reconstruction, and the per-token
     emission timestamps.  ``result()`` re-raises the exception that stopped
-    the session, if one did."""
+    the session, if one did.
+
+    Timestamps are ``time.monotonic()`` seconds: ``submitted_at``,
+    ``admitted_at`` and ``first_token_at``; ``token_steps`` holds the id of
+    the decode-step record (``repro.serving.tracing``) that emitted each
+    token."""
 
     def __init__(self, rid):
         self.rid = rid
+        self.submitted_at = time.monotonic()
         self._event = threading.Event()
         self._lock = threading.Lock()
         self._tokens: List[int] = []
         self._recon_steps = 0
-        self._times: List[float] = []
+        self._times: List[float] = []    # [admitted, token 0, token 1, ...]
+        self._steps: List[Optional[int]] = []
         self._error: Optional[BaseException] = None
         self.completed_by = None         # "model" | "error"
 
@@ -185,12 +194,33 @@ class GenerationFuture:
             t = self._times
             return [1e3 * (b - a) for a, b in zip(t, t[1:])]
 
+    @property
+    def admitted_at(self) -> Optional[float]:
+        with self._lock:
+            return self._times[0] if self._times else None
+
+    @property
+    def first_token_at(self) -> Optional[float]:
+        with self._lock:
+            return self._times[1] if len(self._times) > 1 else None
+
+    @property
+    def token_steps(self) -> List[Optional[int]]:
+        """Per emitted token, the id of the decode step that emitted it;
+        None for token 0, which comes from the prefill at admission."""
+        with self._lock:
+            return list(self._steps)
+
     def _emit(self, token, now, reconstructed):
         with self._lock:
             self._tokens.append(int(token))
             self._times.append(now)
             if reconstructed:
                 self._recon_steps += 1
+
+    def _link(self, step):
+        with self._lock:
+            self._steps.append(step)
 
     def _finish(self, how="model"):
         self.completed_by = how
@@ -273,20 +303,21 @@ def _transformer_substrate(cfg, mesh):
     from repro.models import transformer as T
 
     # jitted: an eager prefill re-traces its layer scan, and so recompiles
-    # the whole stack, on every call
-    prefill_tok = jax.jit(
-        lambda params, tokens, cache_len: T.prefill(
-            cfg, params, tokens=tokens, cache_len=cache_len),
-        static_argnums=2)
-    prefill_emb = jax.jit(
-        lambda params, embeds, cache_len: T.prefill(
-            cfg, params, embeds=embeds, cache_len=cache_len),
-        static_argnums=2)
+    # the whole stack, on every call.  Each program is named for what it
+    # serves (``jit_member_decode`` etc. in a profiler trace).
+    def member_prefill(params, tokens, cache_len):
+        return T.prefill(cfg, params, tokens=tokens, cache_len=cache_len)
+
+    def parity_prefill(params, embeds, cache_len):
+        return T.prefill(cfg, params, embeds=embeds, cache_len=cache_len)
+
+    member_prefill = jax.jit(member_prefill, static_argnums=2)
+    parity_prefill = jax.jit(parity_prefill, static_argnums=2)
 
     def prefill_fn(params, tokens=None, embeds=None, cache_len=0):
         if embeds is not None:
-            return prefill_emb(params, embeds, cache_len)
-        return prefill_tok(params, tokens, cache_len)
+            return parity_prefill(params, embeds, cache_len)
+        return member_prefill(params, tokens, cache_len)
 
     def step(params, cache, pos, **inp):
         logits, new = T.decode_step(cfg, params, cache, pos, **inp)
@@ -296,17 +327,18 @@ def _transformer_substrate(cfg, mesh):
                 new, cache_shardings(mesh, new))
         return logits, new
 
-    decode_jit = jax.jit(
-        lambda params, cache, pos, token: step(params, cache, pos,
-                                               token=token))
-    decode_emb_jit = jax.jit(
-        lambda params, cache, pos, embed: step(params, cache, pos,
-                                               embed=embed))
+    @jax.jit
+    def member_decode(params, cache, pos, token):
+        return step(params, cache, pos, token=token)
+
+    @jax.jit
+    def parity_decode(params, cache, pos, embed):
+        return step(params, cache, pos, embed=embed)
 
     def decode_fn(params, cache, pos, token=None, embed=None):
         if embed is not None:
-            return decode_emb_jit(params, cache, pos, embed)
-        return decode_jit(params, cache, pos, token)
+            return parity_decode(params, cache, pos, embed)
+        return member_decode(params, cache, pos, token)
 
     def embed_fn(params, tokens):
         return T.embed_tokens(cfg, params, jnp.asarray(tokens))
@@ -423,10 +455,14 @@ class GenerationSession:
                      embed=self._embed(self.params, tok0))
         # ... and the reconstruction decode (its shapes never change), whose
         # first call would otherwise stall a step past its deadline
+        def reconstruct(parity_outs, outputs, missing, parity_avail):
+            return self.scheme.decode(parity_outs, outputs, missing,
+                                      parity_avail)
+        self._reconstruct = jax.jit(reconstruct)
         zeros = jnp.zeros(np.shape(logits0), jnp.float32)
-        self.scheme.decode(jnp.stack([zeros] * self.r),
-                           jnp.stack([zeros] * self.k),
-                           jnp.arange(self.k) == 0, jnp.ones((self.r,), bool))
+        self._reconstruct(jnp.stack([zeros] * self.r),
+                          jnp.stack([zeros] * self.k),
+                          jnp.arange(self.k) == 0, jnp.ones((self.r,), bool))
 
         self._members = [_Executor(f"lm-member-{i}") for i in range(self.k)]
         self._parities = [_Executor(f"lm-parity-{j}") for j in range(self.r)]
@@ -439,11 +475,7 @@ class GenerationSession:
         self._idle = threading.Event()   # set while nothing queued/active
         self._idle.set()
         self._error: Optional[BaseException] = None
-        self._gaps_ms: List[float] = []
-        self._completed_by: Dict[str, int] = {}
-        self._recon_steps = 0
-        self._t0 = None
-        self._t1 = None
+        self._futures: List[GenerationFuture] = []
         self._next_rid = 0
         self._scheduler = threading.Thread(target=self._loop,
                                            name="lm-scheduler", daemon=True)
@@ -462,6 +494,7 @@ class GenerationSession:
             # queued under the lock: an abort either sees this request in
             # its drain or this submit already raised
             fut = GenerationFuture(rid)
+            self._futures.append(fut)
             self._idle.clear()
             self._waiting.put((rid, [int(t) for t in prompt],
                                max_new_tokens or self.spec.max_new_tokens,
@@ -477,26 +510,39 @@ class GenerationSession:
         return done
 
     def stats(self) -> ServingReport:
+        """Inter-token gaps from token 1 on (token 0's wait is time to first
+        token), and every emitted token over first admission to last
+        emission, from the futures' stamps."""
         with self._lock:
-            gaps = np.asarray(self._gaps_ms, float)
-            n = len(gaps)
-            span = (self._t1 - self._t0) if (self._t0 is not None
-                                             and self._t1 is not None
-                                             and self._t1 > self._t0) else 0.0
-            pct = (lambda q: float(np.percentile(gaps, q))) if n else \
-                (lambda q: float("nan"))
-            return ServingReport(
-                engine="threads", strategy="parm",
-                scheme=getattr(self.scheme, "name", str(self.spec.scheme)),
-                scenario=getattr(self.scenario, "name", None),
-                n=n, median_ms=pct(50), p99_ms=pct(99), p999_ms=pct(99.9),
-                mean_ms=float(gaps.mean()) if n else float("nan"),
-                max_ms=float(gaps.max()) if n else float("nan"),
-                completed_by=dict(self._completed_by),
-                reconstructions=self._recon_steps,
-                tokens_per_s=(n / span) if span else 0.0,
-                inter_token_p50_ms=pct(50), inter_token_p999_ms=pct(99.9),
-                reconstructed_steps=self._recon_steps)
+            futs = list(self._futures)
+        gaps, n_tokens, recon, t0, t1 = [], 0, 0, [], []
+        for f in futs:
+            with f._lock:
+                times, n, r = list(f._times), len(f._tokens), f._recon_steps
+            gaps.extend(1e3 * (b - a) for a, b in zip(times[1:], times[2:]))
+            n_tokens += n
+            recon += r
+            if n:
+                t0.append(times[0])
+                t1.append(times[-1])
+        gaps = np.asarray(gaps, float)
+        n = len(gaps)
+        span = max(t1) - min(t0) if t0 else 0.0
+        pct = (lambda q: float(np.percentile(gaps, q))) if n else \
+            (lambda q: float("nan"))
+        by = {"model": n - recon, "parity": recon}
+        return ServingReport(
+            engine="threads", strategy="parm",
+            scheme=getattr(self.scheme, "name", str(self.spec.scheme)),
+            scenario=getattr(self.scenario, "name", None),
+            n=n, median_ms=pct(50), p99_ms=pct(99), p999_ms=pct(99.9),
+            mean_ms=float(gaps.mean()) if n else float("nan"),
+            max_ms=float(gaps.max()) if n else float("nan"),
+            completed_by={k: v for k, v in by.items() if v},
+            reconstructions=recon,
+            tokens_per_s=(n_tokens / span) if span > 0 else 0.0,
+            inter_token_p50_ms=pct(50), inter_token_p999_ms=pct(99.9),
+            reconstructed_steps=recon)
 
     def shutdown(self):
         """Stop the scheduler and the executors; re-raises the exception
@@ -571,18 +617,37 @@ class GenerationSession:
                 self._step(active)
         # flush: nothing active remains by construction
 
-    def _sleep_for(self, iid):
+    def _fault_delay(self, iid, **ids):
+        """Sleep for the delay injected on instance ``iid``: it stands for a
+        slow instance's extra work, so it is a span of its own."""
         if self._delay_fn is None:
-            return 0.0
+            return
         try:
-            return float(self._delay_fn(iid) or 0.0)
+            d = float(self._delay_fn(iid) or 0.0)
         except TypeError:
-            return 0.0
+            return
+        if d:
+            with _span("lm.fault_delay", **ids):
+                time.sleep(d)
+
+    @staticmethod
+    def _wait(job, rec, deadline=None):
+        """Wait for an executor job (until ``deadline``, if given) and add
+        the time waited to ``rec.wait_s``; True when the job finished.
+        Re-raises the job's exception."""
+        evt, out = job
+        t = time.monotonic()
+        done = evt.wait(None if deadline is None else max(0.0, deadline - t))
+        rec.wait_s += time.monotonic() - t
+        if done and "error" in out:
+            raise out["error"]
+        return done
 
     def _admit(self):
         """Fill free (member, slot) pairs from the waiting queue; rebuild
-        parity columns whose occupancy changed."""
-        admitted = False
+        parity columns whose occupancy changed.  A pass that does either is
+        kept as one ``AdmitRecord``."""
+        rec = None
         while True:
             free = [(i, s) for i in range(self.k)
                     for s in range(self.n_slots)
@@ -593,50 +658,52 @@ class GenerationSession:
                 rid, prompt, max_new, fut = self._waiting.get_nowait()
             except queue.Empty:
                 break
+            if rec is None:
+                rec = AdmitRecord(RECORDER.next_id(), time.monotonic())
             i, s = free[0]
-            stream = _Stream(rid, prompt, max_new, fut)
-            self._slots[i][s] = stream
-            if self._t0 is None:
-                with self._lock:
-                    self._t0 = time.monotonic()
+            ids = {"rid": rid, "admit": rec.id}
+            with _span("lm.admit.request", **ids):
+                stream = _Stream(rid, prompt, max_new, fut)
+                self._slots[i][s] = stream
+                toks = jnp.asarray([prompt], jnp.int32)            # [1, P]
 
-            toks = jnp.asarray([prompt], jnp.int32)            # [1, P]
-            ex = self._members[i]
+                def job(toks=toks, i=i, s=s, ids=ids):
+                    self._fault_delay(self._member_iids[i], **ids)
+                    with _span("lm.member.dispatch", **ids):
+                        logits, one = self._prefill(self.params, tokens=toks,
+                                                    cache_len=self.max_seq)
+                    with _span("lm.slot_write", **ids):
+                        self._caches[i] = self._lay_out(jax.tree.map(
+                            lambda pool, new: pool.at[:, s:s + 1].set(new),
+                            self._caches[i], one))
+                    with _span("lm.member.fetch", **ids):
+                        return np.asarray(logits[0, -1])
 
-            def job(toks=toks, i=i, s=s, stream=stream):
-                iid = self._member_iids[i]
-                d = self._sleep_for(iid)
-                if d:
-                    time.sleep(d)
-                logits, one = self._prefill(self.params, tokens=toks,
-                                            cache_len=self.max_seq)
-                self._caches[i] = self._lay_out(jax.tree.map(
-                    lambda pool, new: pool.at[:, s:s + 1].set(new),
-                    self._caches[i], one))
-                return np.asarray(logits[0, -1])
-
-            evt, out = ex.submit(job)
-            evt.wait()
-            if "error" in out:
-                raise out["error"]
+                pending = self._members[i].submit(job)
+            self._wait(pending, rec)
             # first token comes from the prefill logits (admission path,
             # uncoded); decode steps from here on are coded
-            tok = int(np.argmax(out["result"]))
+            tok = int(np.argmax(pending[1]["result"]))
             now = time.monotonic()
             stream.future._times.append(stream.t_admit)
             stream.future._emit(tok, now, reconstructed=False)
+            stream.future._link(None)
             stream.next_token = tok
-            self._record(now - stream.t_admit, reconstructed=False)
             self._dirty.add(s)
-            admitted = True
+            rec.admitted += 1
             if stream.max_new <= 1:
                 self._finish(i, s)
-        if admitted or self._dirty:
+        if self._dirty:
+            if rec is None:
+                rec = AdmitRecord(RECORDER.next_id(), time.monotonic())
             for s in sorted(self._dirty):
-                self._rebuild_parity(s)
+                self._rebuild_parity(s, rec)
             self._dirty.clear()
+            rec.t1 = time.monotonic()
+            if rec.admitted or rec.rebuilt:
+                RECORDER.append(rec)
 
-    def _rebuild_parity(self, s):
+    def _rebuild_parity(self, s, rec):
         """Re-prefill parity slot column s from the encoded histories of its
         current occupants (right-aligned; empty members contribute zeros).
 
@@ -653,103 +720,113 @@ class GenerationSession:
             for j in range(self.r):
                 self._ppos[j, s] = 0
             return
-        # encoded prompt embeddings [1, L, D]
-        embs = []
-        for h in hists:
-            if h:
-                e = np.asarray(self._embed(self.params,
-                                           jnp.asarray([h], jnp.int32)))
-            else:
-                e = None
-            embs.append(e)
-        D = next(e.shape[-1] for e in embs if e is not None)
-        dt = next(e.dtype for e in embs if e is not None)
-        for j in range(self.r):
-            enc = np.zeros((1, L, D), np.float32)
-            for i, e in enumerate(embs):
-                if e is not None:
-                    enc[:, L - e.shape[1]:] += self.coeffs[j, i] * \
-                        e.astype(np.float32)
-            enc = jnp.asarray(enc.astype(dt))
+        ids = {"admit": rec.id, "slot": s}
+        jobs = []
+        with _span("lm.admit.rebuild", **ids):
+            # encoded prompt embeddings [1, L, D]
+            embs = []
+            for h in hists:
+                if h:
+                    e = np.asarray(self._embed(self.params,
+                                               jnp.asarray([h], jnp.int32)))
+                else:
+                    e = None
+                embs.append(e)
+            D = next(e.shape[-1] for e in embs if e is not None)
+            dt = next(e.dtype for e in embs if e is not None)
+            for j in range(self.r):
+                enc = np.zeros((1, L, D), np.float32)
+                for i, e in enumerate(embs):
+                    if e is not None:
+                        enc[:, L - e.shape[1]:] += self.coeffs[j, i] * \
+                            e.astype(np.float32)
+                enc = jnp.asarray(enc.astype(dt))
 
-            def job(enc=enc, j=j, s=s):
-                _, one = self._prefill(self.parity_params, embeds=enc,
-                                       cache_len=self.max_seq)
-                self._pcaches[j] = self._lay_out(jax.tree.map(
-                    lambda pool, new: pool.at[:, s:s + 1].set(new),
-                    self._pcaches[j], one))
-                return None
+                def job(enc=enc, j=j):
+                    with _span("lm.parity.dispatch", **ids):
+                        _, one = self._prefill(self.parity_params, embeds=enc,
+                                               cache_len=self.max_seq)
+                    with _span("lm.slot_write", **ids):
+                        self._pcaches[j] = self._lay_out(jax.tree.map(
+                            lambda pool, new: pool.at[:, s:s + 1].set(new),
+                            self._pcaches[j], one))
 
-            evt, out = self._parities[j].submit(job)
-            evt.wait()
-            if "error" in out:
-                raise out["error"]
+                jobs.append(self._parities[j].submit(job))
+        for j, job in enumerate(jobs):
+            self._wait(job, rec)
             self._ppos[j, s] = L
+        rec.rebuilt += 1
 
     def _step(self, active):
-        """One coded decode step for every active stream."""
+        """One coded decode step for every active stream, kept as one
+        ``StepRecord``."""
         k, n_slots = self.k, self.n_slots
-        tok = np.zeros((k, n_slots, 1), np.int32)
-        pos = np.zeros((k, n_slots), np.int32)
-        occ = np.zeros((k, n_slots), bool)
-        for i, s in active:
-            st = self._slots[i][s]
-            tok[i, s, 0] = st.next_token
-            pos[i, s] = st.pos
-            occ[i, s] = True
+        rec = StepRecord(RECORDER.next_id(), time.monotonic(),
+                         active=len(active))
+        ids = {"step": rec.id}
+        with _span("lm.step.inputs", **ids):
+            tok = np.zeros((k, n_slots, 1), np.int32)
+            pos = np.zeros((k, n_slots), np.int32)
+            occ = np.zeros((k, n_slots), bool)
+            for i, s in active:
+                st = self._slots[i][s]
+                tok[i, s, 0] = st.next_token
+                pos[i, s] = st.pos
+                occ[i, s] = True
 
-        # member jobs: full fixed-shape batch, per-slot positions
-        member_out = []
-        for i in range(k):
-            ti, pi = jnp.asarray(tok[i]), jnp.asarray(pos[i])
+            # member jobs: full fixed-shape batch, per-slot positions
+            member_out = []
+            for i in range(k):
+                ti, pi = jnp.asarray(tok[i]), jnp.asarray(pos[i])
+                mids = {**ids, "member": i}
 
-            def job(i=i, ti=ti, pi=pi):
-                d = self._sleep_for(self._member_iids[i])
-                if d:
-                    time.sleep(d)
-                logits, new = self._decode(self.params, self._caches[i],
-                                           pi, token=ti)
-                self._caches[i] = new
-                return np.asarray(logits)          # [n_slots, 1, V]
+                def job(i=i, ti=ti, pi=pi, mids=mids):
+                    self._fault_delay(self._member_iids[i], **mids)
+                    with _span("lm.member.dispatch", **mids):
+                        logits, self._caches[i] = self._decode(
+                            self.params, self._caches[i], pi, token=ti)
+                    with _span("lm.member.fetch", **mids):
+                        return np.asarray(logits)          # [n_slots, 1, V]
 
-            member_out.append(self._members[i].submit(job))
+                member_out.append(self._members[i].submit(job))
 
         # parity jobs: encoded input embedding, own cache column positions.
         # Unoccupied (member, slot) cells carry token 0 only for shape — mask
         # their embeddings to zero so they contribute nothing to the code.
-        embs = np.asarray(
-            self._embed(self.params, jnp.asarray(tok.reshape(k * n_slots, 1)))
-        ).reshape(k, n_slots, 1, -1)
-        embs = embs * occ[:, :, None, None]
-        parity_out = []
-        active_slots = {s for _, s in active}
-        for j in range(self.r):
-            enc = np.einsum("i,ind->nd", self.coeffs[j],
-                            embs[:, :, 0]).astype(embs.dtype)[:, None]
-            enc_j = jnp.asarray(enc)
-            ppos_j = jnp.asarray(self._ppos[j].astype(np.int32))
+        with _span("lm.step.embed", **ids):
+            embs = np.asarray(self._embed(
+                self.params, jnp.asarray(tok.reshape(k * n_slots, 1)))
+            ).reshape(k, n_slots, 1, -1)
+        with _span("lm.step.encode", **ids):
+            embs = embs * occ[:, :, None, None]
+            parity_out = []
+            active_slots = sorted({s for _, s in active})
+            for j in range(self.r):
+                enc = np.einsum("i,ind->nd", self.coeffs[j],
+                                embs[:, :, 0]).astype(embs.dtype)[:, None]
+                enc_j = jnp.asarray(enc)
+                ppos_j = jnp.asarray(self._ppos[j].astype(np.int32))
+                pids = {**ids, "parity": j}
 
-            def pjob(j=j, enc_j=enc_j, ppos_j=ppos_j):
-                d = self._sleep_for(self._parity_iids[j])
-                if d:
-                    time.sleep(d)
-                logits, new = self._decode(self.parity_params,
-                                           self._pcaches[j], ppos_j,
-                                           embed=enc_j)
-                self._pcaches[j] = new
-                return np.asarray(logits)
-            parity_out.append(self._parities[j].submit(pjob))
-            self._ppos[j][list(active_slots)] += 1
+                def pjob(j=j, enc_j=enc_j, ppos_j=ppos_j, pids=pids):
+                    self._fault_delay(self._parity_iids[j], **pids)
+                    with _span("lm.parity.dispatch", **pids):
+                        logits, self._pcaches[j] = self._decode(
+                            self.parity_params, self._pcaches[j], ppos_j,
+                            embed=enc_j)
+                    with _span("lm.parity.fetch", **pids):
+                        return np.asarray(logits)
+
+                parity_out.append(self._parities[j].submit(pjob))
+                self._ppos[j][active_slots] += 1
 
         # collect with the per-step straggle deadline
         deadline = time.monotonic() + self.spec.straggle_ms / 1e3
         logits = [None] * k
         missing = []
-        for i, (evt, out) in enumerate(member_out):
-            if evt.wait(max(0.0, deadline - time.monotonic())):
-                if "error" in out:
-                    raise out["error"]
-                logits[i] = out["result"]
+        for i, job in enumerate(member_out):
+            if self._wait(job, rec, deadline):
+                logits[i] = job[1]["result"]
             else:
                 missing.append(i)
 
@@ -757,70 +834,61 @@ class GenerationSession:
         if missing:
             pavail = np.zeros((self.r,), bool)
             plogits = [None] * self.r
-            for j, (evt, out) in enumerate(parity_out):
-                if evt.wait(max(0.0, deadline - time.monotonic())):
-                    if "error" in out:
-                        raise out["error"]
-                    plogits[j] = out["result"]
+            for j, job in enumerate(parity_out):
+                if self._wait(job, rec, deadline):
+                    plogits[j] = job[1]["result"]
                     pavail[j] = True
             if len(missing) <= int(pavail.sum()):
-                V = next(x for x in logits if x is not None).shape[-1] \
-                    if any(x is not None for x in logits) else \
-                    plogits[int(np.argmax(pavail))].shape[-1]
-                outs = np.stack([
-                    x if x is not None else
-                    np.zeros((n_slots, 1, V), np.float32)
-                    for x in logits])                       # [k, n, 1, V]
-                # an available member's unoccupied slots decoded garbage
-                # (token 0) that the parity never encoded — mask them so
-                # the residual subtraction stays exact
-                outs = outs * occ[:, :, None, None]
-                pouts = np.stack([
-                    p if p is not None else
-                    np.zeros((n_slots, 1, V), np.float32)
-                    for p in plogits])                      # [r, n, 1, V]
-                mask = np.zeros((k,), bool)
-                mask[missing] = True
-                rec = np.asarray(self.scheme.decode(
-                    jnp.asarray(pouts, jnp.float32),
-                    jnp.asarray(outs, jnp.float32),
-                    jnp.asarray(mask), jnp.asarray(pavail)))
-                for i in missing:
-                    logits[i] = rec[i]
-                    reconstructed.add(i)
+                with _span("lm.step.reconstruct", **ids):
+                    V = next(x for x in logits if x is not None).shape[-1] \
+                        if any(x is not None for x in logits) else \
+                        plogits[int(np.argmax(pavail))].shape[-1]
+                    outs = np.stack([
+                        x if x is not None else
+                        np.zeros((n_slots, 1, V), np.float32)
+                        for x in logits])                   # [k, n, 1, V]
+                    # an available member's unoccupied slots decoded garbage
+                    # (token 0) that the parity never encoded — mask them so
+                    # the residual subtraction stays exact
+                    outs = outs * occ[:, :, None, None]
+                    pouts = np.stack([
+                        p if p is not None else
+                        np.zeros((n_slots, 1, V), np.float32)
+                        for p in plogits])                  # [r, n, 1, V]
+                    mask = np.zeros((k,), bool)
+                    mask[missing] = True
+                    fixed = np.asarray(self._reconstruct(
+                        jnp.asarray(pouts, jnp.float32),
+                        jnp.asarray(outs, jnp.float32),
+                        jnp.asarray(mask), jnp.asarray(pavail)))
+                    for i in missing:
+                        logits[i] = fixed[i]
+                        reconstructed.add(i)
             else:
                 # irrecoverable this step: block for the stragglers
                 for i in missing:
-                    evt, out = member_out[i]
-                    evt.wait()
-                    if "error" in out:
-                        raise out["error"]
-                    logits[i] = out["result"]
+                    self._wait(member_out[i], rec)
+                    logits[i] = member_out[i][1]["result"]
 
         # emit canonical tokens; feed them back regardless of which side
         # (member or parity decode) produced the logits
-        now = time.monotonic()
-        for i, s in active:
-            st = self._slots[i][s]
-            recon = i in reconstructed
-            tok_out = int(np.argmax(logits[i][s, 0]))
-            gap = now - st.future._times[-1]
-            st.future._emit(tok_out, now, reconstructed=recon)
-            self._record(gap, reconstructed=recon)
-            st.next_token = tok_out
-            st.pos += 1
-            if len(st.future.tokens_so_far) >= st.max_new or \
-                    st.pos >= self.max_seq - 1:
-                self._finish(i, s)
-
-    def _record(self, gap_s, *, reconstructed):
-        with self._lock:
-            self._gaps_ms.append(1e3 * gap_s)
-            key = "parity" if reconstructed else "model"
-            self._completed_by[key] = self._completed_by.get(key, 0) + 1
-            if reconstructed:
-                self._recon_steps += 1
-            self._t1 = time.monotonic()
+        with _span("lm.step.emit", **ids):
+            now = time.monotonic()
+            for i, s in active:
+                st = self._slots[i][s]
+                tok_out = int(np.argmax(logits[i][s, 0]))
+                st.future._emit(tok_out, now, reconstructed=i in reconstructed)
+                st.future._link(rec.id)
+                st.next_token = tok_out
+                st.pos += 1
+                if len(st.future.tokens_so_far) >= st.max_new or \
+                        st.pos >= self.max_seq - 1:
+                    self._finish(i, s)
+        rec.t1 = time.monotonic()
+        rec.missed = tuple(missing)
+        rec.reconstructed = tuple(sorted(reconstructed))
+        rec.stalled = bool(missing) and not reconstructed
+        RECORDER.append(rec)
 
     def _finish(self, i, s):
         st = self._slots[i][s]

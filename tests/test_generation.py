@@ -94,7 +94,8 @@ def test_matches_reference_greedy_decode():
     toks, report, _ = _run(_spec(params, fns), prompts)
     for p, t in zip(prompts, toks):
         assert t == _reference(params, fns, p, 5)
-    assert report.n == 3 * 5
+    # one inter-token gap per decode step: token 0 comes from prefill
+    assert report.n == 3 * (5 - 1)
     assert report.reconstructed_steps == 0
 
 
@@ -189,7 +190,7 @@ def test_slot_recycling_under_oversubscription():
     for p, t in zip(prompts, toks):
         assert t == _reference(params, fns, p, 3)
     assert sorted(f.rid for f in futs) == list(range(9))
-    assert report.n == 9 * 3
+    assert report.n == 9 * (3 - 1)
 
 
 # -------------------------------------------------------------------------
