@@ -378,7 +378,9 @@ def self_attention_decode(cfg, p, x, cache, pos, rope_cs, *, window=0,
     streams, each at its own position — ``rope_cs`` then holds per-row
     tables [B, hd//2]).  ``backend`` as in :func:`self_attention_fwd`.
 
-    Returns (out, new_cache)."""
+    Returns (out, new_rows): the step's own K/V rows {'k','v'} [B,1,KV,hd],
+    not the cache — the caller writes them into its pool (one row per
+    stream), so the pool can be updated in place."""
     q, k, v = _qkv(cfg, p, x, x)
     cos, sin = rope_cs            # tables for the single position, [1, hd//2]
     vector = bool(jnp.ndim(pos))
@@ -390,9 +392,11 @@ def self_attention_decode(cfg, p, x, cache, pos, rope_cs, *, window=0,
         k = apply_rope(k, cos, sin)
     S = cache["k"].shape[1]
     slot = (pos % S) if window else pos
+    # The cache attention reads: the pool with this step's row in place.
+    # The jnp path fuses it into the attention read; it is never stored.
     if vector:
-        # Per-row slot write.  jnp.where keeps untouched rows bit-identical
-        # (no arithmetic on them), which the slot-isolation guarantee of the
+        # jnp.where keeps untouched rows bit-identical (no arithmetic on
+        # them), which the slot-isolation guarantee of the
         # continuous-batching engine relies on.
         sel = (jnp.arange(S)[None, :] == slot[:, None])[:, :, None, None]
         k_cache = jnp.where(sel, k, cache["k"])
@@ -411,7 +415,7 @@ def self_attention_decode(cfg, p, x, cache, pos, rope_cs, *, window=0,
         o = attention_decode_xla(q, k_cache, v_cache, pos, window=window)
     B, _, H, hd = o.shape
     out = o.reshape(B, 1, H * hd) @ p["wo"]
-    return out, {"k": k_cache, "v": v_cache}
+    return out, {"k": k, "v": v}
 
 
 def init_attn_cache(cfg, batch, seq_len, cross_len=0):

@@ -164,7 +164,9 @@ def _bidir_attn(cfg, p, h, ctx):
 
 
 def _layer_decode(cfg, spec, p, x, lcache, pos, ctx):
-    """One-token layer step. Returns (x, new_cache_entry)."""
+    """One-token layer step. Returns (x, update): an attention layer's new
+    K/V rows [B,1,KV,hd] and an SSM layer's whole state; a cross-attention
+    cache is read only, so it is left out."""
     new = {}
     if spec["mixer"] == "attn":
         h = L.apply_norm(cfg, p["attn"]["norm"], x)
@@ -184,7 +186,6 @@ def _layer_decode(cfg, spec, p, x, lcache, pos, ctx):
         o, _ = L.cross_attention_fwd(cfg, p["cross"], h, (ck, cv),
                                      from_cache=True)
         x = x + o
-        new["cross"] = lcache["cross"]
     if spec["ffn"] == "mlp":
         h = L.apply_norm(cfg, p["mlp"]["norm"], x)
         x = x + L.mlp_fwd(cfg, p["mlp"], h)
@@ -216,7 +217,21 @@ def _stack_fwd(cfg, stacked, x, ctx, plan, remat=False):
     return x, aux, caches
 
 
+def _write_rows(pool, rows, pos, window):
+    """Write each stream's new row into a stacked pool: pool [G,B,S,KV,hd],
+    rows [G,B,1,KV,hd], row b at slot ``pos[b]`` (``pos % S`` under a
+    sliding window).  A scatter of B rows a layer: with the pool donated,
+    XLA updates it in place."""
+    B, S = pool.shape[1], pool.shape[2]
+    slot = jnp.broadcast_to(pos % S if window else pos, (B,))
+    return pool.at[:, jnp.arange(B), slot].set(
+        rows[:, :, 0], indices_are_sorted=True, unique_indices=True)
+
+
 def _stack_decode(cfg, stacked, caches, x, pos, ctx, plan):
+    """The scan emits each layer's update, not its cache (``_layer_decode``);
+    after it, attention rows are written into their pools, SSM states
+    replace theirs and cross-attention caches pass through as given."""
     def body(x, inp):
         gp, gc = inp
         new = []
@@ -225,8 +240,15 @@ def _stack_decode(cfg, stacked, caches, x, pos, ctx, plan):
             new.append(c)
         return x, tuple(new)
 
-    x, new_caches = jax.lax.scan(body, x, (stacked, caches))
-    return x, new_caches
+    x, updates = jax.lax.scan(body, x, (stacked, caches))
+    new_caches = []
+    for old, upd in zip(caches, updates):
+        new = {**old, **upd}
+        if "attn" in upd:
+            new["attn"] = {n: _write_rows(old["attn"][n], upd["attn"][n], pos,
+                                          ctx["window"]) for n in ("k", "v")}
+        new_caches.append(new)
+    return x, tuple(new_caches)
 
 
 # --------------------------------------------------------------------------

@@ -327,11 +327,13 @@ def _transformer_substrate(cfg, mesh):
                 new, cache_shardings(mesh, new))
         return logits, new
 
-    @jax.jit
+    # the pool is donated: the step writes one row per stream into it in
+    # place, and the caller replaces its pool with the one returned
+    @functools.partial(jax.jit, donate_argnums=1)
     def member_decode(params, cache, pos, token):
         return step(params, cache, pos, token=token)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=1)
     def parity_decode(params, cache, pos, embed):
         return step(params, cache, pos, embed=embed)
 
@@ -339,6 +341,9 @@ def _transformer_substrate(cfg, mesh):
         if embed is not None:
             return parity_decode(params, cache, pos, embed)
         return member_decode(params, cache, pos, token)
+    # the jitted programs themselves, for compile-level checks
+    decode_fn.programs = {"member_decode": member_decode,
+                          "parity_decode": parity_decode}
 
     def embed_fn(params, tokens):
         return T.embed_tokens(cfg, params, jnp.asarray(tokens))
@@ -446,13 +451,16 @@ class GenerationSession:
 
         # warm the decode paths (jit compile) before any deadline is armed —
         # a first-step compile would otherwise read as a multi-second
-        # straggle on every instance at once, which no code survives
+        # straggle on every instance at once, which no code survives.  The
+        # decode may consume (donate) the pool it is given, so the returned
+        # pools are kept: they differ only in rows that admission overwrites.
         tok0 = jnp.zeros((self.n_slots, 1), jnp.int32)
         pos0 = jnp.zeros((self.n_slots,), jnp.int32)
-        logits0, _ = self._decode(self.params, self._caches[0], pos0,
-                                  token=tok0)
-        self._decode(self.parity_params, self._pcaches[0], pos0,
-                     embed=self._embed(self.params, tok0))
+        logits0, self._caches[0] = self._decode(self.params, self._caches[0],
+                                                pos0, token=tok0)
+        _, self._pcaches[0] = self._decode(
+            self.parity_params, self._pcaches[0], pos0,
+            embed=self._embed(self.params, tok0))
         # ... and the reconstruction decode (its shapes never change), whose
         # first call would otherwise stall a step past its deadline
         def reconstruct(parity_outs, outputs, missing, parity_avail):

@@ -241,6 +241,42 @@ def test_transformer_substrate_end_to_end():
         assert t == ref
 
 
+def test_transformer_decode_consumes_its_pool():
+    """Every decode step of the transformer substrate donates the pool it
+    is given: the previous pool is deleted once the step has run, and a
+    donation XLA could not use is an error here, not a warning."""
+    import warnings
+
+    import jax
+    from repro.configs.base import get_config
+    from repro.models import transformer as T
+    from repro.serving import generation
+    cfg = get_config("qwen2-0.5b", reduced=True)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    spec = GenerationSpec(cfg=cfg, params=params, k=2, r=1, scheme="sum",
+                          batching=BatchingPolicy(max_size=2),
+                          max_seq_len=16, max_new_tokens=3,
+                          straggle_ms=10_000.0)
+    generation._transformer_substrate.cache_clear()   # compiled anew here
+    given = []
+    with warnings.catch_warnings():
+        warnings.filterwarnings("error", message=".*donated buffers")
+        with deploy_lm(spec, engine="threads") as sess:
+            real = sess._decode
+
+            def decode(params, cache, pos, **inp):
+                given.append(cache)
+                return real(params, cache, pos, **inp)
+            sess._decode = decode
+            fut = sess.submit([1, 2, 3])
+            assert sess.wait_all(120.0)
+            assert len(fut.result(1.0)) == 3
+    generation._transformer_substrate.cache_clear()
+    assert len(given) == 2 * (spec.k + spec.r)      # two coded decode steps
+    for pool in given:
+        assert all(x.is_deleted() for x in jax.tree.leaves(pool))
+
+
 @pytest.mark.parametrize("scenario", ["bursty", "storm"])
 def test_sim_engine_coded_beats_uncoded_tail(scenario):
     """Roofline-calibrated token-level DES on a big config: below the

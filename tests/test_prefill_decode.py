@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import get_config
+from repro.models import layers as L
 from repro.models import transformer as T
 
 KEY = jax.random.PRNGKey(0)
@@ -91,3 +92,51 @@ def test_vector_pos_decode_rows_independent():
                                  token=tok[b:b + 1])
         np.testing.assert_allclose(np.asarray(log_v[b]), np.asarray(log_b[0]),
                                    atol=2e-4, rtol=2e-4)
+
+
+def _step_rows(cfg, params, cache, pos, tok):
+    """The K/V rows a vector-``pos`` decode step computes, layer by layer:
+    no layer scan and no pool write."""
+    x = params["embed"][tok]
+    rope = L.rope_tables(pos, cfg.resolved_head_dim, cfg.rope_theta)
+    ctx = {"rope": rope, "window": cfg.sliding_window}
+    rows = []
+    for g in range(cfg.n_groups):
+        gp = jax.tree.map(lambda a: a[g], params["blocks"])
+        gc = jax.tree.map(lambda a: a[g], cache)
+        for i, spec in enumerate(T.layer_plan(cfg)):
+            x, upd = T._layer_decode(cfg, spec, gp[i], x, gc[i], pos, ctx)
+            rows.append((i, g, upd["attn"]))
+    return rows
+
+
+@pytest.mark.parametrize("window", [0, 8])
+def test_decode_writes_one_row_per_stream(window):
+    """From a random pool, a vector-``pos`` step changes only (b, slot[b])
+    of every layer's K and V — slot = pos[b], or pos[b] % S under a sliding
+    window — and that row holds the step's K/V; every other (row, position)
+    is bit-identical."""
+    cfg = _cfg("qwen2-0.5b").replace(sliding_window=window)
+    params = T.init_params(cfg, KEY)
+    S = 8
+    pos = jnp.array([0, S - 1, 3] + ([S + 2, 3 * S - 1] if window else []),
+                    jnp.int32)
+    B = pos.shape[0]
+    pool = T.init_cache(cfg, B, S)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(jax.tree.leaves(pool)))
+    pool = jax.tree.unflatten(jax.tree.structure(pool), [
+        jax.random.normal(k, x.shape, x.dtype)
+        for k, x in zip(keys, jax.tree.leaves(pool))])
+    tok = jax.random.randint(KEY, (B, 1), 0, cfg.vocab)
+    _, new = T.decode_step(cfg, params, pool, pos, token=tok)
+    slot = np.asarray(pos % S if window else pos)
+    written = np.zeros((B, S), bool)
+    written[np.arange(B), slot] = True
+    for i, g, rows in _step_rows(cfg, params, pool, pos, tok):
+        for n in ("k", "v"):
+            old = np.asarray(pool[i]["attn"][n][g])
+            got = np.asarray(new[i]["attn"][n][g])
+            np.testing.assert_array_equal(got[~written], old[~written])
+            np.testing.assert_allclose(got[np.arange(B), slot],
+                                       np.asarray(rows[n][:, 0]),
+                                       rtol=1e-5, atol=1e-6)

@@ -84,3 +84,36 @@ def test_kernel_compiles_for_mosaic(one_chip, name):
     # interpret=False is every builder's default: this is the chip's lowering
     compiled = jax.jit(builder).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["member_decode", "parity_decode"])
+def test_decode_program_updates_pool_in_place(one_chip, program):
+    """The serving decode programs at OLMo-1B width (8 slots, 1024
+    positions) alias their donated KV pool to the pool they return and hold
+    no pool-sized temporary: a step writes one row per stream.  Two layers
+    stand for sixteen; the layer scan keeps the program's shape the same."""
+    from repro.configs.base import get_config
+    from repro.models import transformer as T
+    from repro.serving.generation import _transformer_substrate
+
+    cfg = get_config("olmo-1b").replace(n_layers=2)
+
+    def abstract(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = abstract(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = abstract(jax.eval_shape(lambda: T.init_cache(cfg, B, SEQ)))
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool))
+    feed = ((B, 1), jnp.int32) if program == "member_decode" else \
+        ((B, 1, cfg.d_model), BF)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in (((B,), jnp.int32), feed)]
+    _, decode_fn, _, _ = _transformer_substrate(cfg, None)
+    compiled = decode_fn.programs[program].lower(params, pool,
+                                                 *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 16
