@@ -14,7 +14,7 @@ A plan is a JSON file under ``faults/``.  Kinds:
   parity can rescue, changes with the seed.
 
 The plan's clock starts at ``arm()``: set-up runs without faults, except the
-delays that ``force`` asks for to warm up reconstruction.
+delays that ``force`` and ``force_next`` ask for to warm up reconstruction.
 """
 from __future__ import annotations
 
@@ -43,7 +43,10 @@ class FaultPlan:
             raise ValueError(f"unknown fault plan kind {kind!r}")
         self._jitter = random.Random(f"{seed}/jitter")
         self._origin = None
+        self._origin_mono = None     # the same instant on time.monotonic
         self._forced = {}
+        self._next = None            # (iids, seconds, taken): force_next
+        self.forced_until = 0.0      # monotonic end of the last forced delay
         self._lock = threading.Lock()
         self.injected = 0
 
@@ -79,13 +82,45 @@ class FaultPlan:
         self._forced.setdefault(iid, {"n": 0, "jobs": {}})["jobs"][job] = \
             seconds
 
+    def force_next(self, iids, seconds: float) -> threading.Event:
+        """Before ``arm``: the next job that any of ``iids`` starts waits
+        ``seconds``; the event returned is set once a job has taken it."""
+        taken = threading.Event()
+        with self._lock:
+            self._next = (frozenset(iids), seconds, taken)
+        return taken
+
+    def unforce(self):
+        """Drop a ``force_next`` that no job has taken."""
+        with self._lock:
+            self._next = None
+
     def arm(self):
         """Start the plan's clock; forced warm-up delays end here."""
         self._forced = {}
+        self._next = None
         self._origin = time.perf_counter()
+        self._origin_mono = time.monotonic()
+
+    def slowed(self, iids, t0: float, t1: float) -> bool:
+        """Did a window on any of ``iids`` overlap [t0, t1] (monotonic
+        seconds, after ``arm``)?"""
+        a = 1e3 * (t0 - self._origin_mono)
+        b = 1e3 * (t1 - self._origin_mono)
+        return any(w0 <= b and a < w1 for iid in iids
+                   for w0, w1, _, _ in self._windows.get(iid, ()))
 
     def delay(self, iid) -> float:
         if self._origin is None:
+            if self._next is not None:
+                with self._lock:
+                    nxt = self._next
+                    if nxt is not None and iid in nxt[0]:
+                        self._next = None
+                        self.forced_until = max(self.forced_until,
+                                                time.monotonic() + nxt[1])
+                        nxt[2].set()
+                        return nxt[1]
             f = self._forced.get(iid)
             if f is None:
                 return 0.0

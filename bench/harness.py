@@ -2,23 +2,26 @@
 the timed path produced against the plain reference, and report.
 
 ``run.py`` is the command; this module holds everything it does, as plain
-functions that the tests drive on the CPU at reduced sizes.  A cell is a
-coded generation deployment (``deploy_lm``) fed in closed waves
-(``traffic.waves``).  Times are on ``time.monotonic``, the clock the program
-stamps its tokens with.
+functions that the tests drive on the CPU at reduced sizes.  The loop that a
+cell's traffic mix names picks its driver and its check (``LOOPS``): a coded
+generation deployment (``deploy_lm``) fed in closed waves
+(``closed_waves``), or a coded classification deployment (``deploy``) that
+callers keep a fixed number of queries in (``closed_queries``).  Times are
+on ``time.monotonic``, the clock the program stamps its tokens with.
 """
 from __future__ import annotations
 
 import gc
 import importlib.util
 import json
+import math
 import sys
 import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -33,6 +36,10 @@ CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 TRACE_SECONDS = 5.0          # the traced part of a --trace 1 window
 DRAIN_SECONDS = 600.0        # longest wait for work in flight at the close
 FAULT_TAIL_SECONDS = 120.0   # faults go on while the last wave finishes
+WARMUP_TRIES = 4             # attempts at each forced reconstruction
+# a reconstructed query's group partner is looked for this many places per
+# caller either side of it in the order the callers sent their queries
+PARTNER_REACH = 2
 
 
 # ------------------------------------------------------------- loading ---
@@ -58,6 +65,15 @@ def load_module(path: Path):
     return mod
 
 
+def load_mix(path: Path) -> dict:
+    """A traffic mix, whose ``loop`` has to be one of ``LOOPS``."""
+    mix = json.loads(Path(path).read_text())
+    if mix.get("loop") not in LOOPS:
+        raise ValueError(f"{path}: loop must be one of {sorted(LOOPS)}, "
+                         f"got {mix.get('loop')!r}")
+    return mix
+
+
 def load_config(root: Path, entry: dict):
     """(sizes from the JSON file, the model module beside it)."""
     path = root / entry["file"]
@@ -81,7 +97,7 @@ class Cell:
         w = find(bench["workloads"], name, "workload")
         cfg, model = load_config(root, find(bench["configs"], w["config"],
                                             "config"))
-        mix = traffic.load(root / "bench" / "traffic" / f"{w['traffic']}.json")
+        mix = load_mix(root / "bench" / "traffic" / f"{w['traffic']}.json")
         return cls(root, bench, w, cfg, model, mix,
                    root / "bench" / "faults" / f"{mix['faults']}.json")
 
@@ -219,6 +235,7 @@ class Run:
     tw0: float = 0.0                  # traced part of the window
     tw1: float = 0.0
     notes: Dict[str, Any] = field(default_factory=dict)
+    inputs: Any = None                # a query cell's input pool
 
     @property
     def cfg(self):
@@ -533,24 +550,345 @@ def check_lm(run: Run, control: bool = False) -> Dict[str, Any]:
     return out
 
 
+# ------------------------------------------------------------- queries ---
+@dataclass
+class QueryRecord:
+    index: int                        # submission order, warm-up included
+    t_submit: float
+    future: Any = None
+    t_done: float = math.nan          # when the caller had its answer
+    completed_by: str = ""
+    output: Optional[np.ndarray] = None
+    error: Optional[str] = None
+
+
+class QueryDriver:
+    """Sends queries to a deployment, query ``i`` with input ``i`` of the
+    pool (in turn), and keeps a record of each.  Callers on several threads
+    do not wait for one another: as at a frontend that many clients call,
+    which queries form a coding group is the order in which the calls reach
+    it."""
+
+    def __init__(self, sess, inputs):
+        self.sess, self.inputs = sess, inputs
+        self.records: List[QueryRecord] = []
+        self._lock = threading.Lock()
+
+    def submit(self, on: bool = False, until: float = math.inf):
+        """Send the next query; None once ``until`` has passed."""
+        with self._lock:
+            t = time.monotonic()
+            if t >= until:
+                return None
+            rec = QueryRecord(len(self.records), t)
+            self.records.append(rec)
+        with span("bench.submit", on):
+            rec.future = self.sess.submit(
+                self.inputs[rec.index % len(self.inputs)])
+        return rec
+
+    @staticmethod
+    def wait(rec: QueryRecord, on: bool = False):
+        with span("bench.wait", on):
+            try:
+                rec.output = rec.future.result(DRAIN_SECONDS)
+            except Exception as e:          # recorded, counted as failed
+                rec.error = repr(e)
+        rec.t_done = time.monotonic()
+        rec.completed_by = rec.future.completed_by
+
+
+def warm_up_queries(driver: QueryDriver, plan, members, k: int,
+                    delay: float = 0.25):
+    """Warm-up of every program the window uses: two plain groups (the
+    forward of members and parity, the encode), then one reconstruction of
+    each member position of a group, forced by slowing whichever member
+    instance takes that query (the decode; a parity answer that comes
+    before any member takes the query does as well).  Returns the
+    positions reconstructed, once every slowed instance is free again."""
+    for _ in range(2):
+        for rec in [driver.submit() for _ in range(k)]:
+            driver.wait(rec)
+    done = []
+    for j in range(k):
+        for attempt in range(WARMUP_TRIES):
+            group = []
+            for i in range(k):
+                if i == j:
+                    taken = plan.force_next(members, delay * 2 ** attempt)
+                group.append(driver.submit())
+                if i == j:
+                    _await_force(taken, group[-1], plan)
+                if i < j:           # answered before the slow one is sent
+                    driver.wait(group[-1])
+            for rec in group:
+                driver.wait(rec)
+            if group[j].completed_by == "parity":
+                done.append(j)
+                break
+        else:
+            raise RuntimeError(f"warm-up reconstructed no query at member "
+                               f"position {j} in {WARMUP_TRIES} tries")
+    time.sleep(max(0.0, plan.forced_until - time.monotonic()))
+    return done
+
+
+def _await_force(taken, rec: QueryRecord, plan):
+    """Wait until a member instance has taken the forced delay, or the
+    query was answered without one (a member job whose query a parity
+    answered first is dropped at dequeue): then the delay is withdrawn."""
+    deadline = time.monotonic() + DRAIN_SECONDS
+    while not taken.wait(0.01):
+        if rec.future.done():
+            plan.unforce()
+            return
+        if time.monotonic() > deadline:
+            raise RuntimeError("no member instance took a query")
+
+
+def serve_queries(run: Run, plan, seconds: float,
+                  trace_dir: Optional[Path], t_start: float):
+    import jax
+    cell, cfg, model, mix = run.cell, run.cfg, run.model, run.cell.mix
+    pair_coeff(model, cfg)              # refused before anything is served
+    phases = run.notes["setup_phases_s"]
+    t = time.monotonic()
+    weights = model.init_weights(cfg, run.seed)
+    sess = model.deploy(cfg, weights, plan.delay)
+    del weights
+    phases["weights_deploy"] = time.monotonic() - t
+    run.inputs = traffic.inputs(mix, model.input_shape(cfg),
+                                rngs(run.seed, 1))
+    driver = QueryDriver(sess, run.inputs)
+    errors = []
+
+    def client(on):
+        try:
+            while (rec := driver.submit(on, run.w1)) is not None:
+                driver.wait(rec, on)
+        except Exception as e:              # raised again below
+            errors.append(e)
+
+    try:
+        run.notes["warmup_reconstructed"] = warm_up_queries(
+            driver, plan, model.member_instances(cfg),
+            cfg["deployment"]["k"])
+        n_warm = len(driver.records)
+        run.setup_s = time.monotonic() - t_start
+        phases["warm_up"] = run.setup_s - sum(phases.values())
+
+        on = trace_dir is not None
+        plan.arm()
+        run.w0 = time.monotonic()
+        run.w1 = run.w0 + seconds
+        tracer = Tracer(trace_dir, min(TRACE_SECONDS, seconds)) if on \
+            else None
+        clients = [threading.Thread(target=client, args=(on,),
+                                    name=f"bench-client-{c}")
+                   for c in range(mix["clients"])]
+        for t in clients:
+            t.start()
+        for t in clients:
+            t.join()
+        if errors:
+            raise errors[0]
+        if tracer is not None:
+            run.trace = tracer.finish()
+            run.tw0, run.tw1 = tracer.t0, tracer.t1
+        run.notes["device"] = describe(jax.devices()[:cell.workload["chips"]])
+    finally:
+        sess.shutdown()
+    run.requests = driver.records[n_warm:]
+    by = {}
+    for r in run.requests:
+        by[r.completed_by] = by.get(r.completed_by, 0) + 1
+    run.notes["completed_by"] = by
+    if plan.n_windows:
+        run.notes.update(recon_by_slowing(run, plan,
+                                          model.member_instances(cfg)))
+
+
+def recon_by_slowing(run: Run, plan, members) -> Dict[str, Any]:
+    """Answers in the window split by whether a fault window on a member
+    instance overlapped the query's time in flight: how many of each, and
+    the share of each that parity answered (%)."""
+    out = {}
+    done = [r for r in run.requests
+            if r.t_done <= run.w1 and r.completed_by in ("model", "parity")]
+    for key, want in (("slowed", True), ("unslowed", False)):
+        part = [r for r in done
+                if plan.slowed(members, r.t_submit, r.t_done) == want]
+        out[f"answers_{key}"] = len(part)
+        out[f"recon_share_{key}"] = 100.0 * sum(
+            r.completed_by == "parity" for r in part) / max(len(part), 1)
+    return out
+
+
+def query_sample(run: Run, n_members: int, n_recon: int):
+    """A sample, drawn from the seed, of the predictions of queries sent in
+    the window: (members' own, reconstructed ones).  Every reconstructed
+    prediction is in it, up to ``n_recon`` of them."""
+    done = [r for r in run.requests
+            if r.t_submit < run.w1 and r.output is not None]
+    rng = rngs(run.seed, 4)
+
+    def pick(recs, n):
+        idx = rng.choice(len(recs), size=min(n, len(recs)), replace=False)
+        return [recs[i] for i in sorted(idx)]
+    return (pick([r for r in done if r.completed_by == "model"], n_members),
+            pick([r for r in done if r.completed_by == "parity"], n_recon))
+
+
+def _reference_rows(model, cfg, weights, rows, block):
+    """The reference's logits of each input in ``rows`` ([batch, *shape]
+    each): [len(rows), batch, classes], computed in blocks of ``block``
+    inputs, one compiled program for every block."""
+    import jax
+    ref = jax.jit(lambda w, x: model.reference_logits(cfg, w, x))
+    x = np.concatenate(rows)
+    out = []
+    for s in range(0, len(x), block):
+        part = x[s:s + block]
+        got = np.asarray(ref(weights, _pad(part, block)))[:len(part)]
+        out.append(got.astype(np.float64))
+    return np.concatenate(out).reshape(len(rows), len(rows[0]), -1)
+
+
+def _err(got, want, scale):
+    """Widest gap between two logit vectors, over ``scale``."""
+    return float(np.abs(np.asarray(got, np.float64) - want).max() / scale)
+
+
+def pair_coeff(model, cfg) -> float:
+    """The coefficient c of a code whose one parity is c * (x_a + x_b): the
+    sum code with k=2, r=1, the only code whose reconstructions
+    ``check_queries`` can follow (it searches for one partner per
+    reconstructed query); any other is refused."""
+    coeffs = model.code_coeffs(cfg)
+    if coeffs.shape != (1, 2) or coeffs[0, 0] != coeffs[0, 1]:
+        raise ValueError(f"the query check follows reconstructions of the "
+                         f"sum code with k=2, r=1 only, not coefficients "
+                         f"{coeffs.tolist()}")
+    return float(coeffs[0, 0])
+
+
+def check_queries(run: Run) -> Dict[str, Any]:
+    """Logit vectors of a sample of the window's predictions against the
+    plain reference: a member's prediction against the reference forward of
+    its input, over the reference's largest logit; a reconstructed
+    prediction against the reference's own chain — the sum encode of its
+    group's inputs, the parity forward, and the decode (the parity output
+    less the other member's reference output) — over the largest logit of
+    the terms the decode combines.  The callers do not wait for one
+    another, so the other member of a reconstructed query's group is not
+    known from outside: it is the query, among those sent within
+    ``PARTNER_REACH`` x clients places of it and answered by their own
+    instance, whose chain the prediction meets best.  Where the fault plan
+    slows instances, at least one reconstructed prediction has to be among
+    those compared.  The control is a run of its own, served at the
+    configuration's ``control_precision`` (``run_cell(precision=...)``)."""
+    cfg, model = run.cfg, run.model
+    lim = cfg["correct"]
+    c = pair_coeff(model, cfg)
+    members, recons = query_sample(run, lim["sample_members"],
+                                   lim["sample_reconstructed"])
+    inputs = run.inputs
+    by_index = {r.index: r for r in run.requests}
+    reach = PARTNER_REACH * run.cell.mix["clients"]
+    rows, at = [], {}
+
+    def need(key, x):
+        if key not in at:
+            at[key] = len(rows)
+            rows.append(np.asarray(x, np.float32))
+        return at[key]
+
+    def x_of(i):
+        return inputs[i % len(inputs)]
+    m_rows = [need(("x", r.index), x_of(r.index)) for r in members]
+    chains = []                 # per reconstruction: [(partner, P, F)]
+    for r in recons:
+        cands = [by_index.get(i) for i in range(r.index - reach,
+                                                r.index + reach + 1)]
+        chains.append([
+            (p, need(("p",) + tuple(sorted((r.index, p.index))),
+                     (c * x_of(r.index).astype(np.float64)
+                      + c * x_of(p.index)).astype(np.float32)),
+             need(("x", p.index), x_of(p.index)))
+            for p in cands if p is not None and p is not r
+            and p.completed_by == "model"])
+    if not rows:
+        return {"predictions_compared": 0, "reconstructed_compared": 0,
+                "checks": [Check("member_logit_err", math.inf,
+                                 lim["max_member_logit_err"])]}
+    weights = model.init_weights(cfg, run.seed)
+    base = _reference_rows(model, cfg, weights, rows, lim["reference_block"])
+
+    def recon_err(got, par, oth):
+        """A reconstruction ``got`` against the reference chain of one
+        group."""
+        want = (base[par] - c * base[oth]) / c
+        scale = max(np.abs(base[par]).max(), np.abs(c * base[oth]).max())
+        return _err(got, want, scale / c)
+
+    m_err = [_err(r.output, base[i], np.abs(base[i]).max())
+             for r, i in zip(members, m_rows)]
+    r_err, picked = [], []
+    for r, cands in zip(recons, chains):
+        errs = [recon_err(r.output, par, oth) for _, par, oth in cands]
+        best = int(np.argmin(errs)) if errs else None
+        r_err.append(errs[best] if errs else math.inf)
+        picked.append(None if best is None else cands[best])
+    checks = [Check("member_logit_err", max(m_err, default=math.inf),
+                    lim["max_member_logit_err"])]
+    if r_err:
+        checks.append(Check("recon_logit_err", max(r_err),
+                            lim["max_recon_logit_err"]))
+    if run.notes.get("fault_windows"):
+        checks.append(Check("reconstructed_compared", len(r_err), 1,
+                            at_least=True))
+    return {"predictions_compared": len(m_err) + len(r_err),
+            "reconstructed_compared": len(r_err),
+            "member_logit_err": max(m_err, default=math.nan),
+            "recon_logit_err": max(r_err, default=math.nan),
+            "partner_offset_max": max((abs(pk[0].index - r.index)
+                                       for r, pk in zip(recons, picked)
+                                       if pk), default=0),
+            "checks": checks}
+
+
+def query_attempts(run: Run):
+    """(queries sent in the window, those that errored or got no
+    prediction of the model's or the parity's)."""
+    reqs = [r for r in run.requests if r.t_submit < run.w1]
+    failed = sum(1 for r in reqs if r.error or r.output is None
+                 or r.completed_by not in ("model", "parity"))
+    return len(reqs), failed
+
+
 # ------------------------------------------------------------- the run ---
 def run_cell(root: Path, workload: str, seed: int, seconds: float,
              trace: bool, *, devices=None, trace_dir: Optional[Path] = None,
-             t_start: Optional[float] = None, control: bool = False):
+             t_start: Optional[float] = None, control: bool = False,
+             precision: Optional[str] = None):
     """Set up, measure and check one cell; returns (result line, checks,
     run).  ``devices``: the devices to report (default: demand the
-    chips the cell asks for).  ``control``: also read the control (see
-    ``calibrate.py``)."""
+    chips the cell asks for).  ``control``: also read the control, where
+    the check reads it beside the program's outputs (an LM cell's).
+    ``precision``: serve at this matmul precision in place of the
+    configuration's: a query cell's control (see ``calibrate.py``)."""
     import jax
     t_start = time.monotonic() if t_start is None else t_start
     cell = Cell.load(root, workload)
     devs = devices if devices is not None else \
         require_chips(cell.workload["chips"])
     run = Run(cell, seed, devs[0].device_kind, devs[0].platform)
+    # set-up by phase: process start to here (imports, the backend)
+    run.notes["setup_phases_s"] = {"backend": time.monotonic() - t_start}
     plan = faults.load(cell.fault_path, cell.model.instances(cell.cfg),
                        seed, seconds + FAULT_TAIL_SECONDS)
     log = CompileLog()
-    precision = cell.cfg.get("matmul_precision")
+    precision = precision or cell.cfg.get("matmul_precision")
     old = jax.config.jax_default_matmul_precision
     if trace and trace_dir is None:
         raise ValueError("a traced run needs a trace directory")
@@ -559,14 +897,16 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     try:
         if precision:
             jax.config.update("jax_default_matmul_precision", precision)
-        serve_lm(run, plan, seconds, trace_dir if trace else None, t_start)
+        loop = LOOPS[cell.mix["loop"]]
+        loop.serve(run, plan, seconds, trace_dir if trace else None, t_start)
         run.compiles = [e for e in log.events if run.w0 <= e[0] <= run.w1]
         run.notes["compiled_in_window"] = sorted({e[1] for e in run.compiles})
         run.notes["cache_misses"] = log.misses
         run.notes["faults_injected"] = plan.injected
         run.notes["fault_windows"] = plan.n_windows
         gc.collect()        # the deployment's state goes before the reference
-        checked = check_lm(run, control=control)
+        checked = loop.check(run, control=True) if control \
+            else loop.check(run)
     finally:
         jax.config.update("jax_default_matmul_precision", old)
         jax.monitoring.unregister_event_duration_listener(log)
@@ -606,8 +946,26 @@ def result_line(run: Run, checked: dict, trace: bool) -> dict:
     return line
 
 
-def attempts(run: Run):
+def lm_attempts(run: Run):
     """(requests sent in the window, those not served in full)."""
     reqs = [r for r in run.requests if r.t_submit < run.w1]
     failed = sum(1 for r in reqs if r.error or len(r.tokens) != r.max_new)
     return len(reqs), failed
+
+
+def attempts(run: Run):
+    return LOOPS[run.cell.mix["loop"]].attempts(run)
+
+
+@dataclass(frozen=True)
+class Loop:
+    """What a traffic mix's ``loop`` runs: the driver that serves the
+    window, the check of what it produced, and the count of attempts."""
+    serve: Callable
+    check: Callable
+    attempts: Callable
+
+
+LOOPS = {"closed_waves": Loop(serve_lm, check_lm, lm_attempts),
+         "closed_queries": Loop(serve_queries, check_queries,
+                                query_attempts)}

@@ -1,5 +1,7 @@
 """CPU rehearsal of the LM cells through the harness's own functions at
 reduced sizes, and the faults that must turn ``correct`` false."""
+import time
+
 import jax
 import pytest
 
@@ -134,3 +136,65 @@ def test_control_is_not_correct(root):
     control = checked["control"]
     limits = {c.name: c.limit for c in checked["checks"]}
     assert any(control[name] > limit for name, limit in limits.items())
+
+
+def _run_cell_before_dispatch(root, cell_name, seed, seconds):
+    """``run_cell`` as it was before the traffic mix's loop picked the
+    driver: ``serve_lm`` and ``check_lm`` called by name, the requests not
+    served in full counted as failed."""
+    cell = harness.Cell.load(root, cell_name)
+    devs = jax.devices()
+    run = harness.Run(cell, seed, devs[0].device_kind, devs[0].platform)
+    plan = harness.faults.load(cell.fault_path, cell.model.instances(cell.cfg),
+                               seed, seconds + harness.FAULT_TAIL_SECONDS)
+    log = harness.CompileLog()
+    jax.monitoring.register_event_duration_secs_listener(log)
+    try:
+        harness.serve_lm(run, plan, seconds, None, time.monotonic())
+        run.compiles = [e for e in log.events if run.w0 <= e[0] <= run.w1]
+        run.notes["compiled_in_window"] = sorted({e[1] for e in run.compiles})
+        run.notes["faults_injected"] = plan.injected
+        run.notes["fault_windows"] = plan.n_windows
+        checked = harness.check_lm(run)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(log)
+    return run, checked
+
+
+def _before_attempts(run):
+    reqs = [r for r in run.requests if r.t_submit < run.w1]
+    return len(reqs), sum(1 for r in reqs
+                          if r.error or len(r.tokens) != r.max_new)
+
+
+def test_lm_dispatch_changes_nothing_it_reads(root):
+    """The same seed through the driver picked by the mix's loop and
+    through the LM driver called by name: every field that is not a
+    timing agrees, and on one and the same run the line's metric values
+    and compared numbers are those the LM path computes."""
+    cell, seed = "olmo1b-batch-calm", SEED + 1
+    old_run, old_checked = _run_cell_before_dispatch(root, cell, seed, 2.0)
+    line, checked, run = harness.run_cell(root, cell, seed, 2.0, False,
+                                          devices=jax.devices())
+    # the same work: the first wave's prompts, lengths and (where no
+    # step was reconstructed in either run) its tokens
+    first = lambda r: [q for q in r.requests if q.wave == 0]
+    for a, b in zip(first(old_run), first(run), strict=True):
+        assert (a.prompt, a.max_new) == (b.prompt, b.max_new)
+        if not a.reconstructed and not b.reconstructed:
+            assert a.tokens == b.tokens
+    assert line["correct"] is True and all(c.ok for c in old_checked["checks"])
+    assert [(c.name, c.limit, c.at_least) for c in checked["checks"]] == \
+        [(c.name, c.limit, c.at_least) for c in old_checked["checks"]]
+    assert run.notes["compiled_in_window"] == \
+        old_run.notes["compiled_in_window"] == []
+    assert harness.attempts(old_run)[1] == _before_attempts(old_run)[1] == 0
+    # one run, read both ways
+    again = harness.check_lm(run)
+    assert {c.name: c.value for c in again["checks"]} == \
+        {n: v["value"] for n, v in line["compared"].items()}
+    assert harness.attempts(run) == _before_attempts(run) == \
+        (line["attempted"], line["failed"])
+    for name, got in line["metrics"].items():
+        if name != "setup_s":
+            assert harness.reader(root, name)(run) == got["value"]

@@ -7,7 +7,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import faults, peaks, trace, traffic
+from bench import faults, harness, peaks, trace, traffic
 from bench.harness import ROOT, load_module
 
 DATA = Path(__file__).parent / "data"
@@ -45,7 +45,7 @@ def test_to_bucket():
 
 @pytest.mark.parametrize("mix", ["batch-calm", "batch-straggle"])
 def test_waves_fill_evenly(mix):
-    mix = traffic.load(ROOT / "bench" / "traffic" / f"{mix}.json")
+    mix = harness.load_mix(ROOT / "bench" / "traffic" / f"{mix}.json")
     out = mix["output_len"]
     it = traffic.waves(mix, np.random.default_rng(7))
     cycle = [next(it) for _ in range(out["strata"])]
@@ -159,6 +159,16 @@ def test_harness_spans_from_marks():
                    ("bench.submit", 25, 31), ("y", 30, 31)]
 
 
+def test_open_span_runs_to_the_end_mark():
+    # a wait still open when the trace stops, alone on its thread: it runs
+    # to the END mark, not to its own start
+    marks = [("bench.wait", 10, 10)]
+    assert trace._spans(marks) == [("bench.wait", 10, 10)]
+    assert trace._spans(marks, end=50) == [("bench.wait", 10, 50)]
+    closed = [("bench.wait", 10, 10), ("bench.wait/end", 20, 20)]
+    assert trace._spans(closed, end=50) == [("bench.wait", 10, 20)]
+
+
 def test_self_times_of_nested_ops():
     # a loop op around two body ops, then an op after it
     got = trace._self_times([("loop", 0, 10), ("b", 6, 8), ("a", 2, 5),
@@ -198,3 +208,162 @@ def test_reduce_recorded_trace():
     assert gaps == sorted(gaps, reverse=True)
     assert sum(gaps) <= got.window_s - got.busy_s + 1e-9
     assert all(": " in name for name, _ in got.idle_gaps)
+
+
+# ------------------------------------------------------------ queries ---
+def test_query_inputs_same_shape_every_seed():
+    mix = harness.load_mix(ROOT / "bench" / "traffic" / "query-straggle.json")
+    mix = dict(mix, inputs=dict(mix["inputs"], distinct=8))
+    a = traffic.inputs(mix, (32, 32, 3), np.random.default_rng(1))
+    b = traffic.inputs(mix, (32, 32, 3), np.random.default_rng(2))
+    assert a.shape == b.shape == (8, 1, 32, 32, 3)
+    assert a.dtype == np.float32 and not np.array_equal(a, b)
+    assert abs(a.std() - 1) < 0.05 and abs(a.mean()) < 0.05
+    with pytest.raises(ValueError):
+        harness.load_mix(ROOT / "bench" / "faults" / "shuffle.json")
+
+
+def test_fault_plan_forces_the_next_job_of_a_set():
+    plan = faults.FaultPlan({"kind": "none"}, [0, 1, 1000], seed=1,
+                            horizon_s=10)
+    taken = plan.force_next([0, 1], 0.5)
+    assert plan.delay(1000) == 0.0 and not taken.is_set()
+    assert plan.delay(1) == 0.5 and taken.is_set()
+    assert plan.delay(0) == 0.0          # one job only
+    assert plan.forced_until > 0
+    plan.force_next([0], 0.5)
+    plan.unforce()                       # withdrawn before any job took it
+    assert plan.delay(0) == 0.0
+    plan.force_next([0], 0.5)
+    plan.arm()                           # the window forces nothing
+    assert plan.delay(0) == 0.0
+
+
+def test_fault_plan_slowed_reads_its_windows():
+    plan = faults.FaultPlan({"kind": "none"}, [0, 1], seed=1, horizon_s=1)
+    plan._windows = {0: [(100.0, 200.0, 10.0, 10.0)], 1: []}
+    plan.arm()
+    t = plan._origin_mono
+    assert plan.slowed([0], t + 0.15, t + 0.16)
+    assert plan.slowed([0, 1], t + 0.05, t + 0.12)     # overlaps its start
+    assert not plan.slowed([0], t, t + 0.05)
+    assert not plan.slowed([0], t + 0.201, t + 0.3)    # after its end
+    assert not plan.slowed([1], t + 0.15, t + 0.16)
+
+
+def _small_cnn():
+    cfg, mod = _cfg("resnet18-cifar")
+    cfg["model"].update(image_shape=[4, 4, 1], stages=[2, 4],
+                        blocks_per_stage=1, n_classes=3)
+    return cfg, mod
+
+
+def test_resnet_counts_by_hand():
+    cfg, mod = _small_cnn()
+    # stem 3x3x1x2 at 4x4; stage 0 block: two 3x3x2x2 at 4x4; stage 1
+    # block (stride 2, 2x2 out): 3x3x2x4, 3x3x4x4 and a 1x1x2x4 projection;
+    # head 4x3
+    macs = 16 * 9 * 2 + 2 * 16 * 9 * 4 + 4 * (9 * 8 + 9 * 16 + 8) + 12
+    weights = 18 + 2 * 36 + 72 + 144 + 8 + 12 + 3
+    flops, nbytes = mod.forward_work(cfg)
+    assert flops == 2 * macs
+    assert mod.param_count(cfg) == weights
+    assert nbytes == 4 * (weights + 16 + 3)
+
+
+def test_resnet18_published_sizes():
+    cfg, mod = _cfg("resnet18-cifar")
+    flops, nbytes = mod.forward_work(cfg)
+    # ResNet-18's 11,173,962 parameters at CIFAR's 10 classes, less the
+    # 9,600 scales and shifts of the BatchNorm it does not have; ~0.56 GMAC
+    # at 32x32
+    assert mod.param_count(cfg) == 11_173_962 - 9_600
+    assert 1.10e9 < flops < 1.12e9
+    assert nbytes == pytest.approx(4 * 11_164_362, rel=1e-3)
+
+
+def test_resnet_weights_in_the_programs_layout():
+    import jax
+    from repro.models import cnn
+    cfg, mod = _cfg("resnet18-cifar")
+    m = cfg["model"]
+    want = jax.eval_shape(lambda k: cnn.init_resnet(
+        k, tuple(m["image_shape"]), stages=tuple(m["stages"]),
+        n_out=m["n_classes"], blocks_per_stage=m["blocks_per_stage"]),
+        jax.random.PRNGKey(0))
+    got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                       mod.init_weights(cfg, 3))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert jax.tree.leaves(got) == jax.tree.leaves(want)
+
+
+def test_resnet_reference_against_the_program(monkeypatch):
+    """The reference and the program's forward agree to float32 rounding at
+    a small size; the program at the control's precision "high" (three
+    bfloat16 passes, the CPU stand-in) does not."""
+    import jax
+    from bench import rehearsal
+    from repro.models import cnn
+    cfg, mod = _cfg("resnet18-cifar")
+    cfg["model"].update(stages=[8, 16, 16, 32])
+    w = mod.init_weights(cfg, 11)
+    x = np.random.default_rng(0).standard_normal((4, 32, 32, 3),
+                                                 dtype=np.float32)
+    rehearsal.cpu_high_precision(monkeypatch)
+    fwd = {}
+    for precision in ("highest", "high"):
+        with jax.default_matmul_precision(precision):
+            fwd[precision] = np.asarray(
+                jax.jit(lambda w, x: cnn.resnet_fwd(w, x))(w, x), np.float64)
+    got, low = fwd["highest"], fwd["high"]
+    want = np.asarray(mod.reference_logits(cfg, w, x), np.float64)
+    scale = np.abs(want).max(-1)
+    err = (np.abs(got - want).max(-1) / scale).max()
+    ctl = (np.abs(low - want).max(-1) / scale).min()
+    assert err < 1e-6 and ctl > 5 * err, (err, ctl)
+
+
+def test_recorded_trace_gaps_in_an_open_wait():
+    """The recorded trace with a harness wait opened on the submitting
+    thread and never closed, as in a wave longer than the traced part:
+    every idle gap after it is named by the wait.  (The trace was recorded
+    when ``bench.submit`` was one annotation; here it is the two marks the
+    harness writes now.)"""
+    path = next(DATA.glob("*.xplane.pb"))
+    threads, devices = trace.read(path)
+    host = next(evs for evs in threads
+                if any(n == "bench.submit" for n, _, _ in evs))
+    t_wait = next(e for n, _, e in host if n == "bench.submit")
+    host += [("bench.submit" + trace.SPAN_END, t_wait, t_wait),
+             ("bench.wait", t_wait, t_wait)]
+    got = trace.summarize(threads, devices)
+    t1 = next(s for evs in threads for n, s, _ in evs if n == trace.END)
+    assert got.idle_gaps and all(n.startswith("bench.wait: ")
+                                 for n, _ in got.idle_gaps)
+    assert all(e <= t1 for n, s, e in trace._spans(host, t1)
+               if n == "bench.wait")
+
+
+def test_recorded_trace_counts_program_runs():
+    from jax.profiler import ProfileData
+    path = next(DATA.glob("*.xplane.pb"))
+    got = trace.reduce(path)
+    pd = ProfileData.from_file(str(path))
+    host = [e for p in pd.planes if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events]
+    t0 = next(e.start_ns for e in host if e.name == trace.BEGIN)
+    t1 = next(e.start_ns for e in host if e.name == trace.END)
+    dev = next(p for p in pd.planes if p.name == "/device:TPU:0")
+    mods = next(ln for ln in dev.lines if ln.name == "XLA Modules")
+    want = {}
+    for e in mods.events:
+        if t0 <= e.start_ns < t1:
+            name = e.name.split("(")[0]
+            want[name] = want.get(name, 0) + 1
+    assert got.modules == want and sum(want.values()) > 0
+    assert all("(" not in name for name in got.modules)
+    # programs loaded from the persistent cache carry the fingerprint as
+    # a suffix of their own
+    assert trace._program("jit_member_decode_8544231769555158965_") == \
+        trace._program("jit_member_decode(8544231769555158965)") == \
+        "jit_member_decode"

@@ -11,9 +11,10 @@ asynchronous copies (``Async XLA Ops``) overlap compute and are not counted.
 from __future__ import annotations
 
 import glob
+import re
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 BEGIN, END = "bench.trace_begin", "bench.trace_end"
 SPAN_PREFIX, SPAN_END = "bench.", "/end"
@@ -27,6 +28,9 @@ class TraceSummary:
     device_ops: List[Tuple[str, float]] = field(default_factory=list)
     idle_gaps: List[Tuple[str, float]] = field(default_factory=list)
     n_devices: int = 0
+    # executions of each device program that started in the window, over
+    # all devices, by program name ("jit_resnet_fwd")
+    modules: Dict[str, int] = field(default_factory=dict)
 
     @property
     def idle_share(self) -> float:
@@ -74,12 +78,14 @@ def _self_times(events):
     return [(n, s, max(t, 0.0)) for n, s, t in out]
 
 
-def _spans(events):
+def _spans(events, end=None):
     """One thread's events, with the harness's span marks (``name`` at
     the start, ``name/end`` at the end) joined into spans; a span still
-    open at the end of the trace runs to its last event."""
+    open when the trace stops runs to ``end`` (the ``END`` mark), or,
+    without one, to the thread's last event."""
     out, open_ = [], {}
-    last = max((e for _, _, e in events), default=0.0)
+    last = max((e for _, _, e in events), default=0.0) if end is None \
+        else end
     for n, s, e in sorted(events, key=lambda x: x[1]):
         if not n.startswith(SPAN_PREFIX) or n in (BEGIN, END):
             out.append((n, s, e))
@@ -89,8 +95,15 @@ def _spans(events):
                 out.append((name, open_.pop(name), s))
         else:
             open_[n] = s
-    out.extend((n, s, last) for n, s in open_.items())
+    out.extend((n, s, max(s, last)) for n, s in open_.items())
     return out
+
+
+def _program(module_name: str) -> str:
+    """A device program's name without the fingerprint that the trace
+    appends: 'jit_resnet_fwd(1234)' or 'jit_resnet_fwd_1234_' ->
+    'jit_resnet_fwd'."""
+    return re.sub(r"(\(\d+\)|_\d+_)$", "", module_name)
 
 
 def _events(line):
@@ -98,12 +111,13 @@ def _events(line):
             for e in line.events]
 
 
-def reduce(path, top: int = 10) -> Optional[TraceSummary]:
-    """Read one ``.xplane.pb`` and reduce it; see the module docstring.
-    None when the trace holds no device plane (a CPU run)."""
+def read(path):
+    """(host threads, devices) of one ``.xplane.pb``: each host thread a
+    list of (name, start, end) events, each device (plane name, its
+    ``XLA Ops`` events, its ``XLA Modules`` events), times in ns."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(str(path))
-    host, devices = [], []
+    threads, devices = [], []
     for plane in pd.planes:
         lines = {ln.name: ln for ln in plane.lines}
         if plane.name.startswith("/device:") and "XLA Ops" in lines:
@@ -111,12 +125,24 @@ def reduce(path, top: int = 10) -> Optional[TraceSummary]:
                             _events(lines["XLA Modules"])
                             if "XLA Modules" in lines else []))
         elif plane.name.startswith("/host:"):
-            for ln in plane.lines:
-                evs = [ev for ev in _events(ln) if not ev[0].startswith("$")]
-                host.extend(_spans(evs))
+            threads.extend([ev for ev in _events(ln)
+                            if not ev[0].startswith("$")]
+                           for ln in plane.lines)
+    return threads, devices
+
+
+def reduce(path, top: int = 10) -> Optional[TraceSummary]:
+    """Read one ``.xplane.pb`` and reduce it; see the module docstring.
+    None when the trace holds no device plane (a CPU run)."""
+    return summarize(*read(path), top=top)
+
+
+def summarize(threads, devices, top: int = 10) -> Optional[TraceSummary]:
+    """The reduction of what ``read`` returns."""
     if not devices:
         return None
-    marks = {n: s for n, s, _ in host if n in (BEGIN, END)}
+    marks = {n: s for evs in threads for n, s, _ in evs if n in (BEGIN, END)}
+    host = [sp for evs in threads for sp in _spans(evs, marks.get(END))]
     if BEGIN in marks and END in marks:
         t0, t1 = marks[BEGIN], marks[END]
     else:
@@ -126,9 +152,12 @@ def reduce(path, top: int = 10) -> Optional[TraceSummary]:
     if window <= 0:
         raise ValueError(f"{path}: empty traced window")
 
-    busy, per_op = [], {}
+    busy, per_op, runs = [], {}, {}
     gaps0 = None
     for _, ops, modules in devices:
+        for n, s, _ in modules:
+            if t0 <= s < t1:
+                runs[_program(n)] = runs.get(_program(n), 0) + 1
         inside = [(n, max(s, t0), min(e, t1)) for n, s, e in ops
                   if e > t0 and s < t1]
         merged = union((s, e) for _, s, e in inside)
@@ -151,7 +180,7 @@ def reduce(path, top: int = 10) -> Optional[TraceSummary]:
     idle = [(_host_label(host, s, e), (e - s) / 1e9) for s, e in longest]
     return TraceSummary(busy_s=sum(busy) / len(busy), window_s=window,
                         t0_ns=t0, device_ops=device_ops, idle_gaps=idle,
-                        n_devices=len(devices))
+                        n_devices=len(devices), modules=runs)
 
 
 def _host_label(host, s, e):

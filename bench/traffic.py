@@ -1,12 +1,17 @@
 """The one traffic generator: turns a mix's parameters into requests.
 
-A mix is a JSON file under ``traffic/``, with ``loop`` ``closed_waves``
-(batch jobs): a wave of ``wave_size`` requests is sent at once and the next
-wave goes when the last request of this one finished.  Each request draws its
-prompt length from ``prompt_len`` and serves it at the smallest of
-``prompt_len.buckets`` that holds it (the longest bucket for longer ones);
-each wave draws one output length, shared by its requests, from
-``output_len``, clipped to [``lo``, ``hi``].
+A mix is a JSON file under ``traffic/``; its ``loop`` names how it is sent:
+
+* ``closed_waves`` (batch jobs): a wave of ``wave_size`` requests is sent at
+  once and the next wave goes when the last request of this one finished.
+  Each request draws its prompt length from ``prompt_len`` and serves it at
+  the smallest of ``prompt_len.buckets`` that holds it (the longest bucket
+  for longer ones); each wave draws one output length, shared by its
+  requests, from ``output_len``, clipped to [``lo``, ``hi``].
+* ``closed_queries`` (one-shot queries): ``clients`` callers each keep one
+  query of ``batch`` inputs outstanding and send the next when its answer
+  comes.  Inputs come from a pool of ``inputs.distinct`` arrays of the
+  configuration's input shape, each element N(0, 1), sent in turn.
 
 Every seed gets the same multiset of lengths, in its own order
 (``stratified``): the seed reorders the work, it does not change how much
@@ -14,10 +19,8 @@ there is, so runs with different seeds differ no more than runs of one seed.
 """
 from __future__ import annotations
 
-import json
 import math
 from dataclasses import dataclass
-from pathlib import Path
 from statistics import NormalDist
 from typing import Iterator, List
 
@@ -49,14 +52,6 @@ class Request:
     max_new: int
 
 
-def load(path: Path) -> dict:
-    mix = json.loads(Path(path).read_text())
-    if mix.get("loop") != "closed_waves":
-        raise ValueError(f"{path}: loop must be closed_waves, "
-                         f"got {mix.get('loop')!r}")
-    return mix
-
-
 def buckets(mix: dict) -> List[int]:
     """The prompt lengths a mix serves, shortest first."""
     return sorted(mix["prompt_len"]["buckets"])
@@ -80,3 +75,11 @@ def waves(mix: dict, rng) -> Iterator[List[Request]]:
             prompts = to_bucket(stratified(rng, size, mix["prompt_len"]),
                                 sizes)
             yield [Request(int(p), m) for p in prompts]
+
+
+def inputs(mix: dict, shape, rng) -> np.ndarray:
+    """The input pool of a ``closed_queries`` mix: [distinct, batch,
+    *shape] float32.  Every seed draws as many of the same shape."""
+    return rng.standard_normal(
+        (mix["inputs"]["distinct"], mix["batch"]) + tuple(shape),
+        dtype=np.float32)
